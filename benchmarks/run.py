@@ -16,6 +16,7 @@ import time
 
 from benchmarks import figures
 from benchmarks.roofline_report import load_results
+from repro.core import engine
 
 RESULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "results")
@@ -143,6 +144,8 @@ def main(argv=None) -> int:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="")
     args = ap.parse_args(argv)
+    engine.use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     os.makedirs(RESULTS, exist_ok=True)
     print("name,us_per_call,derived")
     all_out = {}

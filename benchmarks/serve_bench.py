@@ -1317,6 +1317,7 @@ def main(argv=None) -> int:
                     help="'cluster' runs just the distributed-serving "
                          "row (the CI multi-replica smoke job)")
     args = ap.parse_args(argv)
+    engine_mod.use_compile_cache(REPO_ROOT)
 
     if args.only == "cluster":
         cluster_row, cluster_bad = run_cluster_row(args.quick, args.seed)

@@ -18,15 +18,19 @@
    fused dispatch, and cache hits overtake the in-flight solve.
 """
 import asyncio
+import os
 
 import numpy as np
 import jax.numpy as jnp
 
+from repro.core import engine
 from repro.planner.einsum_path import (Contraction, plan_contraction,
                                        greedy_plan, cardinalities,
                                        execute_plan)
 from repro.planner.datajoin import Table, JoinSpec, plan_joins, execute
 from repro.service import PlanServer, WorkloadSpec, make_workload
+
+engine.use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
 server = PlanServer(max_batch=8, cache_capacity=1024)
 

@@ -6,13 +6,17 @@ Builds a 12-relation clique query with random (submultiplicative)
 cardinalities — the paper's worst case — and optimizes it under every
 supported cost function, printing the optimal bushy join trees.
 """
+import os
 import time
 
 import numpy as np
 
+from repro.core import engine
 from repro.core.querygraph import clique, random_sparse, \
     make_cardinalities
 from repro.core.dpconv import optimize
+
+engine.use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
 n = 12
 q = clique(n)

@@ -14,6 +14,7 @@ exercises the sync lane of the runtime outside the test suite (the
 concurrent lane lives in examples/planner_demo.py).
 """
 import argparse
+import os
 import sys
 
 from repro.launch.serve import main as serve_main
@@ -64,6 +65,8 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     args, _ = ap.parse_known_args()
+    from repro.core import engine
+    engine.use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     plan_contraction_orders()
     sys.exit(serve_main([
         "--arch", args.arch, "--reduced",

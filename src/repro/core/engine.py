@@ -30,10 +30,12 @@ spike), and ``stats()`` exposes dispatch/solve/round counters that
 ``benchmarks/serve_bench.py`` asserts on.
 
 Exactness: identical to the host paths — all feasibility values are
-exact {0,1} counts (f64 up to n = 26 on the XLA backend, int32 up to
+exact integer counts (int64 up to n = 26 on the XLA backend, int32 up to
 n = 15 on the Pallas backend), the G = 1 probe sequence is the host's
-lockstep pivot sequence, the (min,+) pass reproduces DPsub[out]'s f64
-operations, and the extraction scan applies the host extractors'
+lockstep pivot sequence, every f64 crosses to the device and back as
+int64 bits and the (min,+) pass reproduces DPsub[out]'s f64 operations
+bit for bit (``f64bits``: a TPU's own f64 keeps ~48 significand bits),
+and the extraction scan applies the host extractors'
 witness rule, so optima, C_out values and join trees are bit-identical
 (tests/test_engine.py, tests/test_lattice_parity.py, and the
 serve_bench parity sweep).
@@ -42,14 +44,16 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import jointree, lattice
+from repro.core import f64bits, jointree, lattice
 from repro.core.bitset import popcounts
 from repro.core.lattice import BACKENDS  # noqa: F401  (re-export)
 from repro.obs import metrics as obs_metrics
@@ -122,8 +126,8 @@ class DispatchRecord:
     compile_s: float           # 0.0 on a cache hit
     execute_s: float           # blocked-until-ready device wall time
     rounds: int = 0            # while-loop rounds (filled post-solve)
-    flops: float = 0.0         # xla_cost_analysis, whole program
-    bytes_accessed: float = 0.0
+    flops: "float | None" = None  # XLA cost analysis, whole program;
+    bytes_accessed: "float | None" = None  # None where XLA reports none
     shards: int = 1            # solve-mesh width (1 = single device)
     devices: tuple = ()        # mesh device ids ((platform, ids) pair)
     lane: "int | None" = None  # serving lane that issued the dispatch
@@ -141,6 +145,10 @@ _EXEC_META: dict = {}          # key -> {"compile_s", "flops", ...}
 _PROFILE: collections.deque = collections.deque(maxlen=512)
 _PROFILE_LOCK = threading.Lock()
 _PROFILE_SEQ = 0
+# one trace at a time: programs traced concurrently in one process lower
+# to modules that differ from run to run, so the persistent compile
+# cache never finds them again; compiles still overlap
+_TRACE_LOCK = threading.Lock()
 
 
 def stats() -> EngineStats:
@@ -350,9 +358,12 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
     _STATS.inc("exec_cache_misses")
     mesh = solve_mesh(shards) if shards > 1 else None
     t0 = time.perf_counter()  # timing: measured-duration (compile wall)
+    # every f64 crosses to the device as int64 bits (``f64bits``): the
+    # chip would otherwise keep only ~48 of the 53 significand bits
+    bits = jnp.int64
     args = [
-        jax.ShapeDtypeStruct((B, 1 << n), jnp.float64),
-        jax.ShapeDtypeStruct((B, C), jnp.float64),
+        jax.ShapeDtypeStruct((B, 1 << n), bits),      # cards
+        jax.ShapeDtypeStruct((B, C), bits),           # cand
         jax.ShapeDtypeStruct((B,), jnp.int32),   # lo0 (warm-start floor)
         jax.ShapeDtypeStruct((B,), jnp.int32),   # hi0
     ]
@@ -371,7 +382,7 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
         fn = lattice.build_cap_program(n, direct_layers, backend, extract,
                                        gamma_batch, shards=shards,
                                        mesh=mesh, seeded=seeded)
-        args.append(jax.ShapeDtypeStruct((), jnp.float64))
+        args.append(jax.ShapeDtypeStruct((B, C), bits))   # caps
     elif base_cost == "cap_conn":
         # the no-cross-products cap: pass 2 under connected-split masks
         # (the same ``conn`` input the out program consumes)
@@ -379,7 +390,7 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
                                        gamma_batch, connected=True,
                                        shards=shards, mesh=mesh,
                                        seeded=seeded)
-        args.append(jax.ShapeDtypeStruct((), jnp.float64))
+        args.append(jax.ShapeDtypeStruct((B, C), bits))   # caps
         args.append(jax.ShapeDtypeStruct((B, 1 << n), jnp.bool_))
     elif cost == "out":
         # the connected C_out program has no search loop and no candidate
@@ -390,7 +401,7 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
         fn = lattice.build_out_program(n, extract, shards=shards,
                                        mesh=mesh)
         args = [
-            jax.ShapeDtypeStruct((B, 1 << n), jnp.float64),
+            jax.ShapeDtypeStruct((B, 1 << n), bits),
             jax.ShapeDtypeStruct((B, 1 << n), jnp.bool_),
         ]
     elif cost == "out_seeded":
@@ -401,14 +412,16 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
         fn = lattice.build_out_program(n, extract, shards=shards,
                                        mesh=mesh, seeded=True)
         args = [
-            jax.ShapeDtypeStruct((B, 1 << n), jnp.float64),
+            jax.ShapeDtypeStruct((B, 1 << n), bits),
             jax.ShapeDtypeStruct((B, 1 << n), jnp.bool_),
-            jax.ShapeDtypeStruct((B, 1 << n), jnp.float64),
+            jax.ShapeDtypeStruct((B, 1 << n), bits),
             jax.ShapeDtypeStruct((B, 1 << n), jnp.bool_),
         ]
     else:
         raise ValueError(f"unknown fused cost {cost!r}")
-    exe = jax.jit(fn).lower(*args).compile()
+    with _TRACE_LOCK:
+        lowered = jax.jit(fn).lower(*args)
+    exe = lowered.compile()
     meta = {"key": key, "shards": shards, "devices": devs,
             # timing: measured-duration (AOT compile)
             "compile_s": time.perf_counter() - t0,
@@ -416,18 +429,60 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
                                             gamma_batch=gamma_batch,
                                             extract=bool(extract),
                                             shards=shards),
-            "flops": 0.0, "bytes_accessed": 0.0}
-    try:  # lazy: costmodel pulls in the model stack; optional here
-        from repro.launch.costmodel import xla_cost_analysis
-        ca = xla_cost_analysis(exe)
-        meta["flops"] = float(ca.get("flops", 0.0) or 0.0)
-        meta["bytes_accessed"] = float(ca.get("bytes accessed", 0.0)
-                                       or 0.0)
-    except Exception:
-        pass
+            **_cost_analysis(exe), "memory": _memory_analysis(exe)}
     _EXEC_CACHE[key] = exe
     _EXEC_META[key] = meta
     return exe, meta, False
+
+
+def _cost_analysis(exe) -> dict:
+    """XLA's whole-program ``flops`` and ``bytes_accessed`` for one
+    executable.  A figure XLA does not report is None, never 0, so a
+    reader cannot mistake a missing analysis for a free program."""
+    try:
+        ca = exe.cost_analysis() or {}
+    except (NotImplementedError, jax.errors.JaxRuntimeError):
+        ca = {}
+
+    def get(k):
+        return float(ca[k]) if ca.get(k) is not None else None
+    return {"flops": get("flops"), "bytes_accessed": get("bytes accessed")}
+
+
+def _memory_analysis(exe) -> "dict | None":
+    """The executable's device-memory footprint in bytes, as XLA's
+    ``memory_analysis()`` reports it (None where it reports nothing)."""
+    try:
+        m = exe.memory_analysis()
+    except (NotImplementedError, jax.errors.JaxRuntimeError):
+        return None
+    if m is None:
+        return None
+    return {"argument": int(m.argument_size_in_bytes),
+            "output": int(m.output_size_in_bytes),
+            "temp": int(m.temp_size_in_bytes),
+            "generated_code": int(m.generated_code_size_in_bytes)}
+
+
+def use_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR``, where it is
+    set, wins (JAX reads it itself, so nothing is set here); otherwise
+    the cache lives at ``<root>/.jax_cache`` — one fixed path, because
+    the path is part of what a later run must find again.  Call before
+    the first compile.  Library code and tests never call this."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(root), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def compiled_buckets() -> "list[dict]":
+    """One dict per compiled executable: its bucket ``key``, compile
+    seconds, XLA cost figures, ``memory`` bytes and lattice program
+    card."""
+    return [dict(m) for m in list(_EXEC_META.values())]
 
 
 def candidate_bucket(n: int) -> int:
@@ -445,29 +500,44 @@ def candidate_bucket(n: int) -> int:
     return _next_pow2(max((1 << n) - n - 1, 1))
 
 
-def prewarm(ns, max_batch: int = 16, backend: str = "xla",
-            direct_layers: int = 4, costs=("max",), gamma_batch: int = 1,
-            extract: bool = True, shards: int = 1) -> dict:
-    """Compile the executable buckets a server configured for ``ns`` can
-    hit, before traffic arrives: for each ``n``, every power-of-two
-    batch bucket up to ``max_batch`` (including the chunk-1 tier) at the
-    canonical candidate bucket.  Returns ``{"compiled": k, "seconds":
-    s}``; already-cached buckets are free.
-    """
-    t0 = time.perf_counter()  # timing: measured-duration (prewarm wall)
-    before = _STATS.exec_cache_misses
+PREWARM_THREADS = 4            # concurrent bucket compiles in ``prewarm``
+
+
+def prewarm_jobs(ns, max_batch: int = 16, backend: str = "xla",
+                 direct_layers: int = 4, costs=("max",),
+                 gamma_batch: int = 1, extract: bool = True,
+                 shards: int = 1) -> list:
+    """The executable buckets a server configured for ``ns`` can hit:
+    for each ``n``, every power-of-two batch bucket up to ``max_batch``
+    (including the chunk-1 tier) at the canonical candidate bucket, as
+    ``get_executable`` argument tuples for ``compile_jobs``."""
+    jobs = []
     for n in ns:
         b = 1
         while b <= max_batch:
             for cost in costs:
-                if cost == "out":      # no candidate table, no probing
-                    get_executable(n, b, 0, "xla", 4, extract, "out", 1,
-                                   shards=shards)
+                if cost in ("out", "out_seeded"):  # no candidates/probes
+                    jobs.append((n, b, 0, "xla", 4, extract, cost, 1,
+                                 shards))
                 else:
-                    get_executable(n, b, candidate_bucket(n), backend,
-                                   direct_layers, extract, cost,
-                                   gamma_batch, shards=shards)
+                    jobs.append((n, b, candidate_bucket(n), backend,
+                                 direct_layers, extract, cost, gamma_batch,
+                                 shards))
             b *= 2
+    return jobs
+
+
+def compile_jobs(jobs: list) -> dict:
+    """Compile ``prewarm_jobs`` buckets before traffic arrives, up to
+    ``PREWARM_THREADS`` at once: XLA compiles outside the GIL, and on a
+    TPU host a cold prewarm is otherwise minutes of one busy core.
+    Returns ``{"compiled": k, "seconds": s}``; already-cached buckets
+    are free."""
+    t0 = time.perf_counter()  # timing: measured-duration (prewarm wall)
+    before = _STATS.exec_cache_misses
+    if jobs:
+        with ThreadPoolExecutor(min(len(jobs), PREWARM_THREADS)) as pool:
+            list(pool.map(lambda j: get_executable(*j), jobs))
     compiled = _STATS.exec_cache_misses - before
     _STATS.inc("prewarmed", compiled)
     return {"compiled": compiled,
@@ -623,7 +693,8 @@ def fused_dpconv_max(cards: np.ndarray, n: int, direct_layers: int = 4,
     prof = _record(cost, n, Bp, C, backend, emeta, hit)
     disp0 = _STATS.dispatches
     rec0 = jointree.recursive_extractions()
-    out = _run(exe, jnp.asarray(cards_pad), jnp.asarray(cand_pad),
+    out = _run(exe, jnp.asarray(f64bits.to_bits(cards_pad)),
+               jnp.asarray(f64bits.to_bits(cand_pad)),
                jnp.asarray(lo0), jnp.asarray(hi0), record=prof)
     trees: list = [None] * B
     dpn = None
@@ -633,7 +704,7 @@ def fused_dpconv_max(cards: np.ndarray, n: int, direct_layers: int = 4,
         trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx), B)
     else:
         opt, rounds = out
-    opt = np.asarray(opt, np.float64)[:B]
+    opt = f64bits.from_bits(opt)[:B]
     rounds = int(rounds)
     prof.rounds = rounds
 
@@ -706,20 +777,20 @@ def fused_out(qs: list, cards: np.ndarray, n: int,
         so[:B] = np.asarray(seed_ok, bool)
         seeded = int(np.count_nonzero(so[:B].any(axis=1)))
         cost = "out_seeded"
-        extra = (jnp.asarray(sv), jnp.asarray(so))
+        extra = (jnp.asarray(f64bits.to_bits(sv)), jnp.asarray(so))
 
     exe, emeta, hit = _executable(n, Bp, 0, "xla", 4, extract_tree,
                                   cost, 1, shards)
     prof = _record(cost, n, Bp, 0, "xla", emeta, hit)
     disp0 = _STATS.dispatches
     rec0 = jointree.recursive_extractions()
-    out = _run(exe, jnp.asarray(cards_pad), jnp.asarray(conn_pad),
-               *extra, record=prof)
+    out = _run(exe, jnp.asarray(f64bits.to_bits(cards_pad)),
+               jnp.asarray(conn_pad), *extra, record=prof)
     trees: list = [None] * B
     dpn = None
     if extract_tree:
         cout, dp, nodes, lidx = out
-        dpn = np.asarray(dp, np.float64)[:B]
+        dpn = f64bits.from_bits(dp)[:B]
         trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx), B)
     else:
         (cout,) = out
@@ -727,7 +798,7 @@ def fused_out(qs: list, cards: np.ndarray, n: int,
                jointree.recursive_extractions() - rec0)
     _STATS.inc("solves")
     _STATS.inc("queries", B)
-    return FusedOutSolve(couts=np.asarray(cout, np.float64)[:B],
+    return FusedOutSolve(couts=f64bits.from_bits(cout)[:B],
                          trees=trees,
                          dispatches=_STATS.dispatches - disp0,
                          dp=dpn, extraction="device", seeded=seeded)
@@ -794,9 +865,11 @@ def fused_ccap(cards: np.ndarray, n: int, gamma_slack: float = 1.0,
     prof = _record(cost, n, Bp, C, backend, emeta, hit)
     disp0 = _STATS.dispatches
     rec0 = jointree.recursive_extractions()
-    out = _run(exe, jnp.asarray(cards_pad), jnp.asarray(cand_pad),
+    caps = cand_pad * np.float64(gamma_slack)     # host f64, exact IEEE
+    out = _run(exe, jnp.asarray(f64bits.to_bits(cards_pad)),
+               jnp.asarray(f64bits.to_bits(cand_pad)),
                jnp.asarray(lo0), jnp.asarray(hi0),
-               jnp.float64(gamma_slack), *extra, record=prof)
+               jnp.asarray(f64bits.to_bits(caps)), *extra, record=prof)
     trees = [None] * B
     if extract_tree:
         gamma, cout, nodes, lidx, rounds = out
@@ -809,8 +882,8 @@ def fused_ccap(cards: np.ndarray, n: int, gamma_slack: float = 1.0,
     _STATS.inc("solves")
     _STATS.inc("queries", B)
     _STATS.inc("rounds", int(rounds))
-    return FusedCapSolve(gammas=np.asarray(gamma, np.float64)[:B],
-                         couts=np.asarray(cout, np.float64)[:B],
+    return FusedCapSolve(gammas=f64bits.from_bits(gamma)[:B],
+                         couts=f64bits.from_bits(cout)[:B],
                          trees=trees, rounds=int(rounds),
                          dispatches=_STATS.dispatches - disp0,
                          extraction="device", seeded=seeded)

@@ -18,7 +18,7 @@ semiring    *feasibility* — {0,1} counting in (+,·), thresholded per layer
             *connected value* — the same (min,+) sweep under per-subset
             valid-split masks (DPccp's csg/cmp search space as bitset
             tensors): ``minplus_connected_layers``
-transforms  XLA f64 butterflies (exact counts to n = 26) or the batched
+transforms  XLA int64 butterflies (exact counts to n = 26) or the batched
             Pallas int32 kernels (exact to n = 15) — ``transforms()``;
             optionally a fused ranked-convolution kernel
 probe       binary search (G = 1) or (G+1)-ary ``gamma_batch`` probing —
@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import f64bits
 from repro.core.bitset import layer_indices, popcounts, submask_table
 
 BACKENDS = ("xla", "pallas")
@@ -78,10 +79,12 @@ class Transforms:
 
 
 def transforms(backend: str) -> Transforms:
-    """The two shipped transform tiers (DESIGN.md §Hardware-adaptation)."""
+    """The two shipped transform tiers (DESIGN.md §Hardware-adaptation).
+    Both count in integers: int64 on the XLA tier (exact to n = 26, and
+    exact on a TPU, whose f64 is a pair of f32), int32 on Pallas."""
     if backend == "xla":
         from repro.core.zeta import mobius, zeta
-        return Transforms("xla", zeta, mobius, jnp.float64)
+        return Transforms("xla", zeta, mobius, jnp.int64)
     if backend == "pallas":
         # int32 counting tier: exact while counts < 2^31 (n <= 15),
         # enforced by the caller (BatchPolicy.pallas_max_n)
@@ -184,7 +187,8 @@ def direct_layer_full_sharded(dp, gate, n: int, k: int, pc, dtype,
         prod = dp[..., sub] * dp[..., comp]        # (..., rows, 2^k)
         ind = (jnp.sum(prod, axis=-1) > 0.5).astype(dtype)
         part = part.at[..., ss].set(ind)
-    layer_full = lax.psum(part, axis) * gate
+    # {0,1} indicators: merge in int32, which every backend all-reduces
+    layer_full = lax.psum(part.astype(jnp.int32), axis).astype(dtype) * gate
     return jnp.where(pc == k, layer_full, jnp.array(0, dtype))
 
 
@@ -214,15 +218,20 @@ def conv_masked(Z, k, n: int, dtype):
     w = jnp.where(d < k - d, 2, jnp.where(d == k - d, 1, 0))
     Zhi = Z[jnp.clip(k - d, 1, n)]
     wb = w.astype(dtype).reshape((D,) + (1,) * (Z.ndim - 1))
-    return jnp.sum(wb * Z[1:D + 1] * Zhi, axis=0)
+    # sum in the DP dtype: under x64 an int32 sum would widen to int64,
+    # which the Pallas tier's int32 kernels cannot take on a TPU
+    return jnp.sum(wb * Z[1:D + 1] * Zhi, axis=0, dtype=dtype)
 
 
 def moebius_at_v(acc, pc, n: int):
     """Moebius transform evaluated at the single point V: the signed
     O(2^n) sum Σ_T (-1)^{n-|T|} conv[T].  Signed partial sums exceed the
-    count bound, so reduce in f64 regardless of the DP dtype."""
-    sign = jnp.where((n - pc) % 2 == 0, 1.0, -1.0)
-    return jnp.sum(acc.astype(jnp.float64) * sign, axis=-1)
+    count bound, so reduce in a wider type: int64 for integer tables
+    (exact), f64 for the host loop's float tables."""
+    wide = (jnp.float64 if jnp.issubdtype(acc.dtype, jnp.floating)
+            else jnp.int64)
+    sign = jnp.where((n - pc) % 2 == 0, 1, -1).astype(wide)
+    return jnp.sum(acc.astype(wide) * sign, axis=-1, dtype=wide)
 
 
 # --------------------------------------------- the feasibility recursion
@@ -283,6 +292,7 @@ def feasibility_layers(gate, n: int, direct_layers: int = 4,
     size = 1 << n
     pc = jnp.asarray(popcounts(n), dtype=jnp.int32)
     dtype = tfm.dtype
+    gate = jnp.asarray(gate).astype(dtype)      # {0,1}: exact in any dtype
     batch = gate.shape[:-1]
     zero = jnp.array(0, dtype)
 
@@ -354,6 +364,55 @@ def feasibility_layers(gate, n: int, direct_layers: int = 4,
 
 
 # ------------------------------------------------- the (min,+) semiring
+def _merge_blocks(part, axis: str):
+    """Merge per-device blocks of one (min,+) layer (f64 bit patterns).
+    Every layer-k set is written by exactly one device and ``part`` is
+    zero elsewhere, so summing the two 32-bit halves of each pattern
+    passes it through exactly.  (A ``pmin`` over +inf fill would say
+    the same, but XLA:TPU all-reduces 64-bit types by sum only.)"""
+    lo = lax.psum((part & 0xFFFFFFFF).astype(jnp.int32), axis)
+    hi = lax.psum((part >> 32).astype(jnp.int32), axis)
+    return (hi.astype(jnp.int64) << 32) | (lo.astype(jnp.int64)
+                                           & 0xFFFFFFFF)
+
+
+def _minplus_sweep(card, set_ok, n: int, split_ok=None, shards: int = 1,
+                   shard_axis: "str | None" = None,
+                   shard_chunk: int = SHARD_CHUNK_ELEMS,
+                   seed_vals=None, seed_ok=None):
+    """The (min,+) layer sweep both value semirings share, on f64 bit
+    patterns: ``dp[S] = (dp[T] + dp[S\\T]) + c(S)`` minimised over the
+    splits (only those with ``split_ok`` on both halves, when given) for
+    sets with ``set_ok``, +inf elsewhere; singletons cost 0.  Every
+    value is >= +0, so the min runs on the bits."""
+    pc = jnp.asarray(popcounts(n), dtype=jnp.int32)
+    inf = jnp.int64(f64bits.INF)
+    dp = jnp.broadcast_to(jnp.where(pc == 1, jnp.int64(0), inf), card.shape)
+
+    def layer(dp, ss, sub, comp):
+        combo = f64bits.add(dp[..., sub], dp[..., comp])   # (..., m, 2^k)
+        if split_ok is not None:
+            combo = jnp.where(split_ok[..., sub] & split_ok[..., comp],
+                              combo, inf)
+        val = f64bits.add(jnp.min(combo, axis=-1), card[..., ss])
+        val = jnp.where(set_ok[..., ss], val, inf)
+        if seed_vals is not None:
+            val = jnp.where(seed_ok[..., ss], seed_vals[..., ss], val)
+        return val
+
+    for k in range(2, n + 1):
+        if shard_axis is not None:
+            part = jnp.zeros(dp.shape, jnp.int64)
+            for ss, sub, comp in _shard_block_tables(
+                    n, k, shards, shard_axis, shard_chunk):
+                part = part.at[..., ss].set(layer(dp, ss, sub, comp))
+            dp = jnp.where(pc == k, _merge_blocks(part, shard_axis), dp)
+        else:
+            sets, subs, comps = direct_layer_indices(n, k)
+            dp = dp.at[..., sets].set(layer(dp, sets, subs, comps))
+    return dp
+
+
 def minplus_value_layers(card, gate_ok, n: int, shards: int = 1,
                          shard_axis: "str | None" = None,
                          shard_chunk: int = SHARD_CHUNK_ELEMS):
@@ -370,40 +429,21 @@ def minplus_value_layers(card, gate_ok, n: int, shards: int = 1,
     prune_gamma=gamma)``: min is order-independent and the add
     association matches.
 
-    ``card`` (..., 2^n) f64; ``gate_ok`` boolean, same shape.
+    ``card`` (..., 2^n) f64 as int64 bit patterns (``f64bits``; so is
+    the returned table); ``gate_ok`` boolean, same shape.
 
     ``shard_axis`` (inside ``shard_map``) partitions each layer's sets
     axis across the mesh: every device computes its block of layer-k
     sets (the dominant ``C(n,k)·2^k`` combo tensor shrinks to 1/D), the
-    blocks meet in ONE ``pmin`` per layer, and a ``pc == k`` select
+    blocks meet in ONE ``psum`` per layer, and a ``pc == k`` select
     folds the merged layer back into the carried table.  Bit-identical
     to the unsharded sweep: per set the full 2^k split axis stays on one
-    device (same min order, same add association), and the pmin just
-    passes that device's value through the +inf everywhere else.
+    device (same min order, same add association), and the psum just
+    passes that device's value through the zeros everywhere else
+    (``_merge_blocks``).
     """
-    pc = jnp.asarray(popcounts(n), dtype=jnp.int32)
-    inf = jnp.array(np.inf, jnp.float64)
-    dp = jnp.broadcast_to(
-        jnp.where(pc == 1, 0.0, inf), card.shape).astype(jnp.float64)
-    for k in range(2, n + 1):
-        if shard_axis is not None:
-            part = jnp.full(dp.shape, inf)
-            for ss, sub, comp in _shard_block_tables(
-                    n, k, shards, shard_axis, shard_chunk):
-                combo = dp[..., sub] + dp[..., comp]   # (..., rows, 2^k)
-                best = jnp.min(combo, axis=-1)
-                val = best + card[..., ss]
-                val = jnp.where(gate_ok[..., ss], val, inf)
-                part = part.at[..., ss].set(val)
-            dp = jnp.where(pc == k, lax.pmin(part, shard_axis), dp)
-        else:
-            sets, subs, comps = direct_layer_indices(n, k)
-            combo = dp[..., subs] + dp[..., comps]     # (..., m, 2^k)
-            best = jnp.min(combo, axis=-1)
-            val = best + card[..., sets]
-            val = jnp.where(gate_ok[..., sets], val, inf)
-            dp = dp.at[..., sets].set(val)
-    return dp
+    return _minplus_sweep(card, gate_ok, n, shards=shards,
+                          shard_axis=shard_axis, shard_chunk=shard_chunk)
 
 
 def minplus_connected_layers(card, conn, n: int, shards: int = 1,
@@ -429,7 +469,8 @@ def minplus_connected_layers(card, conn, n: int, shards: int = 1,
     order-independent, and the add association ``(dp[T] + dp[S\\T]) +
     c(S)`` matches the enumerator's.
 
-    ``card`` (..., 2^n) f64; ``conn`` boolean, same shape (per-query
+    ``card`` (..., 2^n) f64 bit patterns, as in ``minplus_value_layers``;
+    ``conn`` boolean, same shape (per-query
     connected-subset masks — each batch row may carry a different query
     graph).
 
@@ -438,7 +479,7 @@ def minplus_connected_layers(card, conn, n: int, shards: int = 1,
     only ever materialized for this device's block, so the masks shrink
     1/D along with the combo tensor.
 
-    ``seed_vals``/``seed_ok`` (same shape as ``card``; f64 / bool) are
+    ``seed_vals``/``seed_ok`` (same shape as ``card``; f64 bits / bool) are
     the incremental-planning value seeds: where ``seed_ok[S]`` the layer
     write takes ``seed_vals[S]`` instead of the freshly-computed value.
     ``dp[S]`` is a pure function of the sub-problem induced on ``S``
@@ -449,39 +490,9 @@ def minplus_connected_layers(card, conn, n: int, shards: int = 1,
     ones.  Seeded entries still *feed* later layers through the same
     gather reads, so a correct prefix propagates exactly.
     """
-    pc = jnp.asarray(popcounts(n), dtype=jnp.int32)
-    inf = jnp.array(np.inf, jnp.float64)
-    dp = jnp.broadcast_to(
-        jnp.where(pc == 1, 0.0, inf), card.shape).astype(jnp.float64)
-    for k in range(2, n + 1):
-        if shard_axis is not None:
-            part = jnp.full(dp.shape, inf)
-            for ss, sub, comp in _shard_block_tables(
-                    n, k, shards, shard_axis, shard_chunk):
-                split_ok = conn[..., sub] & conn[..., comp]
-                combo = jnp.where(split_ok,
-                                  dp[..., sub] + dp[..., comp], inf)
-                best = jnp.min(combo, axis=-1)
-                val = best + card[..., ss]
-                val = jnp.where(conn[..., ss], val, inf)
-                if seed_vals is not None:
-                    val = jnp.where(seed_ok[..., ss],
-                                    seed_vals[..., ss], val)
-                part = part.at[..., ss].set(val)
-            dp = jnp.where(pc == k, lax.pmin(part, shard_axis), dp)
-        else:
-            sets, subs, comps = direct_layer_indices(n, k)
-            split_ok = conn[..., subs] & conn[..., comps]  # (..., m, 2^k)
-            combo = jnp.where(split_ok,
-                              dp[..., subs] + dp[..., comps], inf)
-            best = jnp.min(combo, axis=-1)
-            val = best + card[..., sets]
-            val = jnp.where(conn[..., sets], val, inf)
-            if seed_vals is not None:
-                val = jnp.where(seed_ok[..., sets],
-                                seed_vals[..., sets], val)
-            dp = dp.at[..., sets].set(val)
-    return dp
+    return _minplus_sweep(card, conn, n, split_ok=conn, shards=shards,
+                          shard_axis=shard_axis, shard_chunk=shard_chunk,
+                          seed_vals=seed_vals, seed_ok=seed_ok)
 
 
 # ------------------------------------------------------ probe strategies
@@ -529,8 +540,10 @@ def extract_scan(dp, n: int, card=None):
     trees: the *largest* T minimizing the witness error, because the
     host's descending ``_submask_iter`` keeps the first (= largest)
     strict minimum.  ``card=None`` reads ``dp`` as a feasibility table
-    (error 0 iff both sides feasible); with ``card`` it reads ``dp`` as
-    a C_out value table (error |dp[T] + dp[S\\T] - (dp[S] - c(S))|).
+    (error 0 iff both sides feasible); with ``card`` it reads ``dp`` and
+    ``card`` as f64 bit patterns of a C_out value table, with the host's
+    f64 error |dp[T] + dp[S\\T] - (dp[S] - c(S))| computed bit for bit
+    (``f64bits``).
 
     Returns ``(nodes, lidx)``: (B, M) int32 — slot masks and left-child
     slot indices (0 for leaves).  ``jointree.tree_from_split_arrays``
@@ -551,12 +564,16 @@ def extract_scan(dp, n: int, card=None):
         comp = S[:, None] & ~T[None, :]
         dpC = jnp.take_along_axis(dp, comp, axis=1)
         if card is None:
-            err = 1.0 - ((dp > 0.5) & (dpC > 0.5)).astype(jnp.float64)
-        else:
-            target = (jnp.take_along_axis(dp, S[:, None], axis=1)
-                      - jnp.take_along_axis(card, S[:, None], axis=1))
-            err = jnp.abs(dp + dpC - target)
-        err = jnp.where(valid, err, jnp.inf)
+            err = 1 - ((dp > 0) & (dpC > 0)).astype(jnp.int32)
+            worst = jnp.int32(2)
+        else:                     # >= +0, so the bits order as values
+            target = f64bits.add(
+                jnp.take_along_axis(dp, S[:, None], axis=1),
+                f64bits.neg(jnp.take_along_axis(card, S[:, None], axis=1)))
+            err = f64bits.abs_(f64bits.add(f64bits.add(dp, dpC),
+                                           f64bits.neg(target)))
+            worst = jnp.int64(f64bits.INF)
+        err = jnp.where(valid, err, worst)
         # largest T among the minima: argmin over the reversed axis
         twit = (size - 1 - jnp.argmin(err[:, ::-1], axis=1)) \
             .astype(jnp.int32)
@@ -703,15 +720,15 @@ def _shard_wrap(fn, mesh):
     lives *inside* the program — per-layer subset blocks picked by
     ``axis_index`` — so callers hand in ordinary host arrays and get
     full-lattice results back, and the AOT shapes match the unsharded
-    builders exactly.  ``check_rep=False``: the replication checker
+    builders exactly.  ``check_vma=False``: the replication checker
     can't see through the scatter/while_loop combines, but every output
     is replicated by construction (each layer ends in a mesh-wide
-    ``psum``/``pmin``)."""
-    from jax.experimental.shard_map import shard_map
+    ``psum``)."""
+    import jax
     from jax.sharding import PartitionSpec
     P = PartitionSpec()
-    return shard_map(fn, mesh=mesh, in_specs=P, out_specs=P,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P, out_specs=P,
+                         check_vma=False)
 
 
 def build_max_program(n: int, direct_layers: int, backend: str,
@@ -720,8 +737,10 @@ def build_max_program(n: int, direct_layers: int, backend: str,
     """The whole-solve DPconv[max] program:
     ``(cards, cand, lo0, hi0) -> (opt[, dp, nodes, lidx], rounds)``.
 
-    Shapes bind at compile time: cards (B, 2^n) f64, cand (B, C) f64,
-    lo0/hi0 (B,) int32 — the initial search bracket (cold solves pass
+    Shapes bind at compile time: cards (B, 2^n) and cand (B, C) as f64
+    bit patterns (int64, ``f64bits``: the gates compare bits, and the
+    optimum comes back as the candidate's exact bits), lo0/hi0 (B,)
+    int32 — the initial search bracket (cold solves pass
     lo0 = 0; with ``seeded=True`` — a separate compile-time variant, the
     cold program's AOT signature never changes — the layer cache passes
     ``lo0 = -(idx + 1)`` and the search VERIFIES the cached-optimum
@@ -760,9 +779,9 @@ def build_max_program(n: int, direct_layers: int, backend: str,
         dp, _, _ = feasibility_layers(gate_of(opt), n, dl, tfm, False,
                                       Z=Zx, scan_middle=True,
                                       shards=shards, shard_axis=axis)
-        dpf = dp.astype(jnp.float64)
-        nodes, lidx = extract_scan(dpf, n)
-        return opt, dpf, nodes, lidx, rounds
+        dp = dp.astype(jnp.int32)                  # {0,1}
+        nodes, lidx = extract_scan(dp, n)
+        return opt, dp, nodes, lidx, rounds
 
     return _shard_wrap(fn, mesh) if axis is not None else fn
 
@@ -777,8 +796,9 @@ def build_out_program(n: int, extract: bool, shards: int = 1,
     ``minplus_connected_layers``).  A separate compile-time variant
     keeps the cold program's AOT signature untouched.
 
-    Shapes bind at compile time: cards (B, 2^n) f64, conn (B, 2^n) bool
-    — the per-query connected-subset masks, precomputed on the host from
+    Shapes bind at compile time: cards (B, 2^n) f64 bit patterns (int64,
+    as are the returned values), conn (B, 2^n) bool — the per-query
+    connected-subset masks, precomputed on the host from
     each query graph (``dpccp.connectivity_masks``).  The (min,+) layer
     sweep runs under per-subset valid-split masks derived from ``conn``
     (the DPccp csg/cmp search space as bitset tensors), and the Alg. 2
@@ -816,14 +836,16 @@ def build_cap_program(n: int, direct_layers: int, backend: str,
                       connected: bool = False, shards: int = 1,
                       mesh=None, seeded: bool = False):
     """The whole-solve C_cap program (paper Sec. 8, both passes fused):
-    ``(cards, cand, lo0, hi0, slack) ->
-    (gamma, cout[, nodes, lidx], rounds)``.
+    ``(cards, cand, lo0, hi0, caps) ->
+    (gamma, cout[, nodes, lidx], rounds)``, every f64 as int64 bits.
 
     Pass 1 is the same lockstep feasibility search as DPconv[max]
     (gamma* = optimal C_max); pass 2 runs the (min,+) value program under
     the gamma-slack gate; pass 3 extracts the C_out witness tree — all
-    inside one dispatch.  ``slack`` is the Sec. 11 resource-aware knob
-    (gamma = slack · gamma*).
+    inside one dispatch.  ``caps`` (B, C) holds, per candidate, the cap
+    its C_max optimum implies — ``cand * slack`` with the Sec. 11
+    resource-aware knob, multiplied on the host so the device multiplies
+    no f64; gamma = caps[optimum index].
 
     ``connected=True`` is the no-cross-products cap: the program grows a
     ``conn`` input (the per-query connected-subset masks
@@ -842,15 +864,14 @@ def build_cap_program(n: int, direct_layers: int, backend: str,
     G = gamma_batch
     axis = _solve_axis(shards, mesh)
 
-    def fn(cards, cand, lo0, hi0, slack, conn=None):
+    def fn(cards, cand, lo0, hi0, caps, conn=None):
         pc = jnp.asarray(pc_np, dtype=jnp.int32)
         gate_of = _gate_builder(cards, pc, tfm.dtype)
         hi, _, rounds = _fused_search(cards, cand, lo0, hi0, n,
                                       direct_layers, tfm, G, gate_of,
                                       shards=shards, shard_axis=axis,
                                       verify_seed=seeded)
-        gamma = jnp.take_along_axis(cand, hi[:, None], axis=1)[:, 0]
-        gamma = gamma * slack
+        gamma = jnp.take_along_axis(caps, hi[:, None], axis=1)[:, 0]
         gate_ok = (cards <= gamma[:, None]) | (pc < 2)
         if connected:
             dpv = minplus_connected_layers(cards, gate_ok & conn, n,
@@ -910,5 +931,5 @@ def program_card(n: int, cost: str, backend: str = "xla",
         "shards": int(shards),
     }
     card["dtype"] = (str(np.dtype(transforms(backend).dtype))
-                     if searched else "float64")
+                     if searched else "float64 bits")
     return card

@@ -15,8 +15,8 @@ Three implementations, all exact:
    This is the hardware adaptation of the paper's C++ bit-loop (DESIGN.md):
    same O-count arithmetic re-blocked into systolic-friendly GEMMs.
 
-3. A hybrid used by the Pallas kernels (see ``repro.kernels``): low ``b`` bits
-   by a (2^b, 2^b) matmul tile in VMEM, remaining bits by butterflies.
+3. The Pallas kernels (see ``repro.kernels``): the same butterflies inside
+   VMEM tiles (lane and sublane rotates), then one pass per block bit.
 
 All functions operate on the LAST axis of an arbitrarily-batched array, so
 ranked tables (n+1, 2^n) transform in one call.
@@ -42,9 +42,10 @@ def _butterfly(f: jnp.ndarray, sign: float) -> jnp.ndarray:
     size = f.shape[-1]
     n = _n_of(size)
     batch = f.shape[:-1]
+    s = jnp.asarray(sign, f.dtype)       # integer tables stay integer
     for j in range(n):
         g = f.reshape(batch + (size // (2 << j), 2, 1 << j))
-        g = g.at[..., 1, :].add(sign * g[..., 0, :])
+        g = g.at[..., 1, :].add(s * g[..., 0, :])
         f = g.reshape(batch + (size,))
     return f
 

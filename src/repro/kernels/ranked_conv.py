@@ -23,8 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.zeta_pallas import LANES, int32_index
+
 SUBLANES = 8
-LANES = 256
 TILE = SUBLANES * LANES
 
 
@@ -39,7 +40,7 @@ def _ranked_conv_kernel(z_ref, o_ref, *, k: int):
 
 
 def ranked_conv_pallas(Z: jnp.ndarray, k: int,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """Z: (n+1, ..., 2^n) ranked zeta table; returns the layer-k
     convolution (..., 2^n).
 
@@ -66,8 +67,9 @@ def ranked_conv_pallas(Z: jnp.ndarray, k: int,
         functools.partial(_ranked_conv_kernel, k=k),
         grid=(rows // SUBLANES,),
         in_specs=[pl.BlockSpec((nranks, SUBLANES, LANES),
-                               lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
+                               lambda i: int32_index(0, i, 0))],
+        out_specs=pl.BlockSpec((SUBLANES, LANES),
+                               lambda i: int32_index(i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), Z.dtype),
         interpret=interpret,
     )(z3)

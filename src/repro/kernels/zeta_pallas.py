@@ -5,25 +5,24 @@ TPU-native decomposition of Yates' O(2^n n) butterfly (DESIGN.md
 
   view f as (ROWS, LANES) with LANES = 256  (index S = row * LANES + col)
 
-  * low  log2(LANES) bits — one (LANES × LANES) GEMM per row-tile with the
-    kron subset matrix  M[a, b] = [b ⊆ a]  (lower-triangular 0/1): runs on
-    the MXU, float32 path.  The int32 path uses in-register reshape
-    butterflies instead (MXU has no exact int32 product; see exactness
-    envelope below).
-  * middle bits (rows inside a block)  — sublane reshape butterflies in
-    VMEM, lane dimension untouched (stays LANES).
+  * low  log2(LANES) bits — lane butterflies inside each (row_block,
+    LANES) tile: a lane rotate (``pltpu.roll``) brings the bit-clear
+    partner alongside, an iota select adds it where the bit is set.
+  * middle bits (rows inside a block)  — the same rotate-and-select
+    butterflies along the sublane axis.
   * high bits (across row-blocks)      — one pairing pass per bit: grid
-    over block pairs, the bit-set block is aliased in/out and accumulated
-    with its bit-clear partner (out = io ± partner).
+    over block pairs, the bit-set block accumulates its bit-clear
+    partner (out = own ± partner).
+
+No scatter and no in-kernel reshape: the TPU lowering has neither.
 
 Exactness envelope (documented, asserted by ops.py):
-  float32 GEMM path   — exact while values stay < 2^24
-  int32 butterfly path — exact while values stay < 2^31
-  beyond that the f64 XLA path in ``repro.core.zeta`` is used (CPU) —
-  TPU would need two-limb emulation; see DESIGN.md.
+  float32 — exact while values stay < 2^24
+  int32   — exact while values stay < 2^31
+  beyond that the int64 XLA path in ``repro.core.zeta`` is used.
 
-All kernels are written for TPU BlockSpec/VMEM tiling and validated with
-``interpret=True`` on CPU against ``ref.py``.
+All kernels compile for the TPU (tests/test_tpu_compile.py) and are
+validated with ``interpret=True`` on CPU against ``ref.py``.
 """
 from __future__ import annotations
 
@@ -31,70 +30,57 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 256
 
 
-@functools.lru_cache(maxsize=8)
-def _subset_matrix(bits: int, inverse: bool) -> np.ndarray:
-    size = 1 << bits
-    a = np.arange(size)[:, None]
-    b = np.arange(size)[None, :]
-    sub = (a & b) == b
-    if not inverse:
-        return sub.astype(np.float32)
-    pc = np.vectorize(lambda x: bin(x).count("1"))(a & ~b)
-    return np.where(sub, (-1.0) ** pc, 0.0).astype(np.float32)
+def int32_index(*idx):
+    """Block indices as int32 whatever ``jax_enable_x64`` says: Mosaic
+    only legalizes an index map that returns 32-bit integers, and a bare
+    Python int constant traces to int64 under x64."""
+    return tuple(jnp.asarray(i, jnp.int32) for i in idx)
 
 
 # --------------------------------------------------------------- kernel 1
-def _local_kernel(x_ref, m_ref, o_ref, *, row_bits: int, sign: float,
-                  use_matmul: bool):
-    """Zeta/Moebius over the low log2(LANES) + row_bits bits of a block."""
+def _butterflies(x, axis: int, bits: int, s):
+    """In-register Yates butterflies over the low ``bits`` bits of
+    ``axis``: every index with bit j set adds ``s`` times its bit-clear
+    partner.  The partner sits 2^j positions lower, so a rotate (XLU)
+    plus an iota select does it — no scatter, no reshape."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    zero = jnp.zeros_like(x)
+    for j in range(bits):
+        # partner[a] = x[a - 2^j]; an int32 shift, as Mosaic requires
+        partner = pltpu.roll(x, jnp.int32(1 << j), axis)
+        x = x + jnp.where(((pos >> j) & 1) == 1, partner * s, zero)
+    return x
+
+
+def _local_kernel(x_ref, o_ref, *, row_bits: int, sign: float):
+    """Zeta/Moebius over the low log2(LANES) + row_bits bits of a block:
+    lane butterflies, then sublane butterflies."""
     x = x_ref[...]                                   # (RB, LANES)
     s = jnp.array(sign, x.dtype)                     # ±1 in the array dtype
-    if use_matmul:
-        # lane transform on the MXU: y[r, a] = Σ_b M[a, b] x[r, b]
-        x = jax.lax.dot_general(
-            x, m_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=x.dtype)
-    else:
-        # lane transform via butterflies (int path)
-        for j in range(LANES.bit_length() - 1):
-            g = x.reshape(x.shape[0], LANES // (2 << j), 2, 1 << j)
-            g = g.at[:, :, 1, :].add(g[:, :, 0, :] * s)
-            x = g.reshape(x.shape[0], LANES)
-    rb = x.shape[0]
-    for j in range(row_bits):                        # sublane butterflies
-        g = x.reshape(rb // (2 << j), 2, 1 << j, LANES)
-        g = g.at[:, 1, :, :].add(g[:, 0, :, :] * s)
-        x = g.reshape(rb, LANES)
-    o_ref[...] = x
+    x = _butterflies(x, 1, LANES.bit_length() - 1, s)
+    o_ref[...] = _butterflies(x, 0, row_bits, s)
 
 
 def _local_pass(f2d: jnp.ndarray, row_block: int, sign: float,
-                inverse: bool, interpret: bool) -> jnp.ndarray:
+                interpret: bool) -> jnp.ndarray:
     rows = f2d.shape[0]
-    use_matmul = jnp.issubdtype(f2d.dtype, jnp.floating)
-    m = jnp.asarray(_subset_matrix(LANES.bit_length() - 1, inverse),
-                    f2d.dtype if use_matmul else jnp.float32)
-    grid = (rows // row_block,)
     return pl.pallas_call(
         functools.partial(_local_kernel,
-                          row_bits=row_block.bit_length() - 1,
-                          sign=sign, use_matmul=bool(use_matmul)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((row_block, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((LANES, LANES), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_block, LANES), lambda i: (i, 0)),
+                          row_bits=row_block.bit_length() - 1, sign=sign),
+        grid=(rows // row_block,),
+        in_specs=[pl.BlockSpec((row_block, LANES),
+                               lambda i: int32_index(i, 0))],
+        out_specs=pl.BlockSpec((row_block, LANES),
+                               lambda i: int32_index(i, 0)),
         out_shape=jax.ShapeDtypeStruct(f2d.shape, f2d.dtype),
         interpret=interpret,
-    )(f2d, m)
+    )(f2d)
 
 
 # --------------------------------------------------------------- kernel 2
@@ -123,11 +109,13 @@ def _pair_pass(f2d: jnp.ndarray, row_block: int, block_bit: int,
         functools.partial(_pair_kernel, block_bit=block_bit, sign=sign),
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((row_block, LANES), lambda i: (i, 0)),
             pl.BlockSpec((row_block, LANES),
-                         lambda i: (i ^ (1 << block_bit), 0)),
+                         lambda i: int32_index(i, 0)),
+            pl.BlockSpec((row_block, LANES),
+                         lambda i: int32_index(i ^ (1 << block_bit), 0)),
         ],
-        out_specs=pl.BlockSpec((row_block, LANES), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((row_block, LANES),
+                               lambda i: int32_index(i, 0)),
         out_shape=jax.ShapeDtypeStruct(f2d.shape, f2d.dtype),
         interpret=interpret,
     )(f2d, f2d)
@@ -135,7 +123,7 @@ def _pair_pass(f2d: jnp.ndarray, row_block: int, block_bit: int,
 
 # ------------------------------------------------------------ entry point
 def zeta_pallas(f: jnp.ndarray, inverse: bool = False,
-                row_block: int = 8, interpret: bool = True) -> jnp.ndarray:
+                row_block: int = 8, interpret: bool = False) -> jnp.ndarray:
     """Zeta (or Moebius, ``inverse=True``) transform over the LAST axis.
 
     Leading axes are a batch dimension (the plan-serving batched solver
@@ -163,7 +151,7 @@ def zeta_pallas(f: jnp.ndarray, inverse: bool = False,
     for b in batch:
         nbatch *= b
     f2d = f.reshape(nbatch * rows, LANES)
-    f2d = _local_pass(f2d, row_block, sign, inverse, interpret)
+    f2d = _local_pass(f2d, row_block, sign, interpret)
     n_block_bits = (rows // row_block).bit_length() - 1
     for jb in range(n_block_bits):             # per-element bits only
         f2d = _pair_pass(f2d, row_block, jb, sign, interpret)

@@ -34,27 +34,9 @@ F32 = 4
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax API drift.
-
-    Older jax returned one properties dict; this jax version returns a
-    list with one dict per program.  Always hand back a flat dict
-    (empty when XLA reports nothing) so callers can ``.get("flops")``.
-    """
-    props = compiled.cost_analysis()
-    if props is None:
-        return {}
-    if isinstance(props, (list, tuple)):
-        merged: dict = {}
-        for p in props:
-            for k, v in (p or {}).items():
-                # numeric counters (flops, bytes accessed, ...) sum
-                # across programs; anything else keeps the last value
-                if isinstance(v, (int, float)) and k in merged:
-                    merged[k] = merged[k] + v
-                else:
-                    merged[k] = v
-        return merged
-    return dict(props)
+    """``compiled.cost_analysis()`` as a flat dict (empty when XLA
+    reports nothing), so callers can ``.get("flops")``."""
+    return dict(compiled.cost_analysis() or {})
 
 
 @dataclasses.dataclass

@@ -7,16 +7,26 @@ pod on 'model' (ICI).
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before any jax import).
+
+Every mesh has ``Auto`` axes: the model code places arrays with
+``with_sharding_constraint`` and the solve programs with ``shard_map``,
+both written for the partitioner to propagate shardings (``jax.make_mesh``
+defaults to ``Explicit`` axes).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -24,7 +34,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     and the CPU train example."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 SOLVE_AXIS = "solve"
@@ -33,7 +43,7 @@ SOLVE_AXIS = "solve"
 def make_solve_mesh(shards: "int | None" = None):
     """1-D mesh for sharded lattice solves: the (min,+)/zeta layer
     sweeps partition their per-layer subset blocks over this axis
-    (``repro.core.lattice`` under ``shard_map``), one ``psum``/``pmin``
+    (``repro.core.lattice`` under ``shard_map``), one ``psum``
     combine per layer.  ``shards=None`` takes every visible device; on
     CPU force more with ``XLA_FLAGS=--xla_force_host_platform_device_
     count=8`` (tests and CI do).
@@ -42,7 +52,7 @@ def make_solve_mesh(shards: "int | None" = None):
     d = n if shards is None else int(shards)
     if not 1 <= d <= n:
         raise ValueError(f"solve mesh wants {d} devices, have {n}")
-    return jax.make_mesh((d,), (SOLVE_AXIS,))
+    return _auto_mesh((d,), (SOLVE_AXIS,))
 
 
 def mesh_fingerprint(mesh) -> tuple:

@@ -51,7 +51,7 @@ examples, benchmarks):
   on-device tree extraction, binary or (G+1)-ary gamma probing
   (``BatchPolicy.gamma_batch``); mid-size lattices route the transforms
   through the batched Pallas kernels (int32, exact to n = 15), the rest
-  use XLA f64 butterflies.  Costs and trees are bit-identical to
+  use XLA int64 butterflies.  Costs and trees are bit-identical to
   single-query ``optimize``.
 * ``router``   — admission policy: (n, edge density, cost fn, latency
   budget) -> (method, lane, params), with an EWMA latency model bucketed

@@ -19,9 +19,10 @@ serving-side concerns:
 * the backend tier: mid-size lattices (``pallas_min_n <= n <=
   pallas_max_n``) run their transforms through the Pallas TPU kernels
   (``repro.kernels.ops``) on an int32 DP — exact for feasibility counts
-  < 2^31, i.e. n <= 15 — while smaller/larger ``n`` stay on the XLA f64
-  butterflies (exact to n = 26).  On this CPU container the Pallas tier
-  runs in interpret mode; on TPU it is the MXU/VPU path.
+  < 2^31, i.e. n <= 15 — while smaller/larger ``n`` stay on the XLA int64
+  butterflies (exact to n = 26).  ``backend="auto"`` takes the Pallas
+  tier on a TPU only; elsewhere the kernels run only when interpret mode
+  is asked for (``repro.kernels.ops.INTERPRET_ENV``).
 * the engine tier (``BatchPolicy.engine``, default ``"fused"``): each
   chunk's ENTIRE solve — search, gate construction, layered DP, and the
   Alg. 2 extraction scan — runs as one compiled lattice program with an
@@ -62,9 +63,8 @@ class BatchPolicy:
     pallas_min_n: int = 12      # Pallas int32 tier lower bound
     pallas_max_n: int = 15      # exactness bound: 2^{2n} < 2^31
     backend: str = "auto"       # "auto" | "xla" | "pallas"
-    # "auto" engages the Pallas tier only on real TPU hardware — off-TPU
-    # the kernels run in interpret mode (a correctness harness, orders of
-    # magnitude slower than XLA); "pallas" forces it anywhere (tests).
+    # "auto" engages the Pallas tier only on a TPU; "pallas" forces it
+    # anywhere (tests, which ask for interpret mode on the CPU).
     engine: str = "fused"       # "fused" | "host"
     # "fused" (default) runs each chunk's whole solve as ONE device
     # dispatch (repro.core.engine over the lattice-program layer);
@@ -166,6 +166,15 @@ class BatchedSolver:
                  lane: int = 0):
         import threading
         self.policy = policy or BatchPolicy()
+        if self.policy.solve_shards > 1:
+            # the server lifts its lane ceilings for this width, so a
+            # mesh narrower than the policy would serve past them
+            import jax
+            have = len(jax.devices())
+            if self.policy.solve_shards > have:
+                raise ValueError(
+                    f"solve_shards={self.policy.solve_shards} but only "
+                    f"{have} {jax.default_backend()} device(s) are visible")
         # one solver models ONE solve lane (the N-lane runtime owns one
         # BatchedSolver per lane); the async runtime's worker thread and
         # a sync front end (plan_one / serve) on the same server may
@@ -206,18 +215,15 @@ class BatchedSolver:
     def _dp_fn(self, n: int):
         if self._use_pallas(n):
             return pallas_dp_fn(n)
-        return None                      # core default: XLA f64 layered DP
+        return None                      # core default: XLA layered DP
 
     def _shards(self, n: int) -> int:
         """Solve-mesh width for one chunk: the policy's width, engaged
-        only at ``n >= shard_min_n`` and clamped to the devices that
-        actually exist (a policy tuned for the 8-device CI host must
-        degrade to single-device on a 1-device box, not crash)."""
+        only at ``n >= shard_min_n``."""
         p = self.policy
         if p.solve_shards <= 1 or n < p.shard_min_n:
             return 1
-        import jax
-        return min(p.solve_shards, len(jax.devices()))
+        return p.solve_shards
 
     def _solve_chunk(self, qs, cards, n, cost, extract_tree,
                      seeds=None):
@@ -228,7 +234,10 @@ class BatchedSolver:
         seed slot and results must not depend on them anyway."""
         engine = self.policy.engine
         G = self.policy.gamma_batch
-        backend = "pallas" if self._use_pallas(n) else "xla"
+        # the Pallas tier serves max chunks; the cap and out programs'
+        # (min,+) passes run on XLA only
+        backend = ("pallas" if cost == "max" and self._use_pallas(n)
+                   else "xla")
         shards = self._shards(n)
         seeds = seeds or [None] * len(qs)
         seed_kw: dict = {}
@@ -264,7 +273,7 @@ class BatchedSolver:
                 kw["shards"] = shards
             if engine == "fused" and cost != "out":
                 kw["gamma_batch"] = G   # out's (min,+) sweep never probes
-                if cost == "max":   # cap's (min,+) pass is f64/xla-only
+                if cost == "max":   # cap's (min,+) pass is XLA-only
                     kw["backend"] = backend
             if seed_kw:             # single-query slice of the batch seed
                 if "seed_opt" in seed_kw:
@@ -274,6 +283,7 @@ class BatchedSolver:
                     kw["seed_ok"] = seed_kw["seed_ok"][0]
             res = optimize(qs[0], cards[0], cost=solve_cost, method=method,
                            extract_tree=extract_tree, **kw, **conn_kw)
+            res.meta["backend"] = kw.get("backend", "xla")
             res.meta["batched"] = False
             res.meta["chunk"] = 1
             return [res]
@@ -327,9 +337,7 @@ class BatchedSolver:
         self.batches_run += 1
         self.queries_batched += len(qs)
         for res in results:
-            # the out program's (min,+) sweep is f64/XLA-only, whatever
-            # the policy's transform tier says for max chunks
-            res.meta["backend"] = "xla" if cost == "out" else backend
+            res.meta["backend"] = backend
             # all chunk members share one solve; consumers averaging
             # per-solve counters weight by 1/chunk
             res.meta["chunk"] = len(qs)

@@ -364,12 +364,18 @@ def _replica_main(rid: str, cfg: dict, conn) -> None:
     asyncio line protocol until a ``shutdown`` frame."""
     import asyncio
 
+    import jax
+
+    # a replica is a host-engine solver on the CPU (ReplicaCluster
+    # refuses any other engine): say so before anything reaches a
+    # backend, so a replica never contends for the parent's accelerator
+    jax.config.update("jax_platforms", "cpu")
+
     from repro.service.batch import BatchPolicy
     from repro.service.runtime import RuntimeConfig, WallClock
     from repro.service.server import PlanServer
 
-    pol = BatchPolicy(engine=cfg.get("engine", "host"),
-                      max_batch=cfg.get("max_batch", 16))
+    pol = BatchPolicy(engine="host", max_batch=cfg.get("max_batch", 16))
     srv = PlanServer(enable_batch=cfg.get("enable_batch", False),
                      batch_policy=pol,
                      lanes=cfg.get("lanes", 1),
@@ -415,8 +421,18 @@ class ReplicaCluster:
                  startup_timeout_s: float = 120.0):
         if n_replicas < 1:
             raise ValueError("need at least one replica")
-        self.n = n_replicas
         self.config = dict(config or {})
+        engine = self.config.get("engine", "host")
+        if engine != "host":
+            # a fused replica would need a chip of its own, and the
+            # parent would have to stay off JAX; this harness assigns
+            # no chips, so it would either fight over one or quietly
+            # solve on the CPU
+            raise ValueError(
+                f"replica engine {engine!r} is not supported: replicas "
+                "are host-engine CPU processes; serve the fused engine "
+                "from one process that holds the chip")
+        self.n = n_replicas
         self.startup_timeout_s = startup_timeout_s
         self.replica_ids = [f"r{i}" for i in range(n_replicas)]
         self.procs: list = []
@@ -429,15 +445,14 @@ class ReplicaCluster:
         import multiprocessing as mp
 
         ctx = mp.get_context("spawn")
-        # replica processes are one-lane host solvers by default: pin
-        # the BLAS pools so N replicas don't oversubscribe the box, and
-        # keep jax off accelerators it would fight over.  Spawn children
-        # inherit os.environ at Process.start() time.
+        # replica processes are one-lane host solvers (each picks the
+        # CPU platform itself, ``_replica_main``): pin the BLAS pools so
+        # N replicas don't oversubscribe the box.  Spawn children inherit
+        # os.environ at Process.start() time.
         pinned = {"OMP_NUM_THREADS": "1", "OPENBLAS_NUM_THREADS": "1",
                   "MKL_NUM_THREADS": "1"}
         saved = {k: os.environ.get(k) for k in pinned}
         os.environ.update(pinned)
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         try:
             pipes = []
             for i, rid in enumerate(self.replica_ids):

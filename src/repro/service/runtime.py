@@ -393,6 +393,13 @@ class _Work:
         self.layer_seeds = 0             # items warm-started by layercache
 
 
+def _total(values) -> "float | None":
+    """Sum of per-dispatch cost figures; None if any dispatch has none
+    (a partial sum would understate the work)."""
+    values = list(values)
+    return None if any(v is None for v in values) else sum(values)
+
+
 # ------------------------------------------------------------------ runtime
 class ServingRuntime:
     """Event-driven deadline-aware scheduler over one ``PlanServer``.
@@ -1207,8 +1214,8 @@ class ServingRuntime:
                 compile_s=sum(r.compile_s for r in prof),
                 execute_s=sum(r.execute_s for r in prof),
                 rounds=sum(r.rounds for r in prof),
-                flops=sum(r.flops for r in prof),
-                bytes_accessed=sum(r.bytes_accessed for r in prof))
+                flops=_total(r.flops for r in prof),
+                bytes_accessed=_total(r.bytes_accessed for r in prof))
         return attrs
 
     def _finalize(self, work: _Work) -> None:

@@ -254,7 +254,7 @@ class PlanServer:
         """
         pol = self.solver.policy
         cfg = self.router.config
-        total = {"compiled": 0, "seconds": 0.0}
+        jobs: list = []
         seen = {(e["n"], e["cost"]) for e in self.prewarm_manifest}
         for cost in costs:
             for n in sorted(set(ns)):
@@ -279,7 +279,7 @@ class PlanServer:
                     max_b = pol.max_batch if n > cfg.small_n else 1
                 # warm the backend the solver will actually pick for this
                 # n: the Pallas tier serves mid-size max chunks, the cap
-                # program's (min,+) value pass is f64/xla-only
+                # and out programs' (min,+) passes run on XLA only
                 backend = "pallas" if (cost == "max"
                                        and self.solver._use_pallas(n)) \
                     else "xla"
@@ -291,21 +291,18 @@ class PlanServer:
                 if pol.engine != "fused":
                     continue                  # manifest only, no compile
                 warm_costs = (cost,)
-                if self.enable_layer_cache and cost in ("max", "cap"):
+                if self.enable_layer_cache:
                     # the layer cache routes seed-carrying solves onto
                     # the ``<cost>_seeded`` program variants (their own
                     # AOT slots) — warm them too or the first seeded
                     # solve per bucket pays a mid-traffic compile, the
                     # exact spike prewarm exists to kill
                     warm_costs = (cost, cost + "_seeded")
-                r = engine_mod.prewarm([n], max_batch=max_b,
-                                       backend=backend,
-                                       direct_layers=4, costs=warm_costs,
-                                       gamma_batch=pol.gamma_batch,
-                                       shards=self.solver._shards(n))
-                total["compiled"] += r["compiled"]
-                total["seconds"] += r["seconds"]
-        return total
+                jobs += engine_mod.prewarm_jobs(
+                    [n], max_batch=max_b, backend=backend, direct_layers=4,
+                    costs=warm_costs, gamma_batch=pol.gamma_batch,
+                    shards=self.solver._shards(n))
+        return engine_mod.compile_jobs(jobs)
 
     def prewarm_from_manifest(self, manifest: "list[dict]") -> dict:
         """Prewarm from a peer replica's ``prewarm_manifest``: group the

@@ -190,8 +190,10 @@ class AdmissionCeilings:
         self.client_shed = 0
 
     def update(self, tenant: str, deny_rate: float) -> None:
-        self._frac[tenant] = max(self.floor,
-                                 1.0 - max(0.0, min(1.0, deny_rate)))
+        # rounded so that a rate given as 1 - f passes exactly f
+        # (1 - (1 - 0.1) is 0.09999999999999998, which floors k * f low)
+        f = round(1.0 - max(0.0, min(1.0, deny_rate)), 12)
+        self._frac[tenant] = max(self.floor, f)
 
     def ceiling(self, tenant: str) -> float:
         return self._frac.get(tenant, 1.0)

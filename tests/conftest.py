@@ -23,10 +23,17 @@ example database, and the full strategy library.
 """
 from __future__ import annotations
 
+import os
 import random
 import sys
 import types
 import zlib
+
+# The suite runs on the CPU, where Pallas kernels can only run in
+# interpret mode, and the kernels use it only when asked
+# (``repro.kernels.ops.INTERPRET_ENV``).  Set before any test imports
+# the program; subprocesses the tests start inherit it.
+os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 _FALLBACK_ACTIVE = False
 _FALLBACK_RUNS: dict = {}       # test qualname -> examples drawn

@@ -286,3 +286,11 @@ def test_tcp_cluster_two_replicas_smoke():
     finally:
         cluster.stop()
     assert procs and all(not p.is_alive() for p in procs)
+
+
+def test_fused_replica_engine_is_refused():
+    """Replicas are host-engine CPU processes; a fused-engine replica
+    would contend for the parent's chip or quietly solve on the CPU, so
+    the cluster refuses it before starting any process."""
+    with pytest.raises(ValueError, match="not supported"):
+        ReplicaCluster(2, config={"engine": "fused"})

@@ -219,3 +219,23 @@ def test_executable_cache_keys_on_shape_buckets():
     # doubled B -> a new (B_bucket,) key, exactly one more compile
     engine.fused_dpconv_max(np.stack(cards + cards), n)
     assert engine.stats().exec_cache_misses == misses0 + 1
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_placement(tmp_path, monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set;
+    without it the cache goes to one fixed path under the given root.
+    ``jax.config.update`` is captured, so the test turns no cache on."""
+    set_calls = []
+    monkeypatch.setattr(engine.jax.config, "update",
+                        lambda k, v: set_calls.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(tmp_path / ".jax_cache")
+        assert engine.use_compile_cache(str(tmp_path)) == want
+        assert set_calls == [("jax_compilation_cache_dir", want)]
+    else:
+        where = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", where)
+        assert engine.use_compile_cache(str(tmp_path)) == where
+        assert set_calls == []

@@ -79,3 +79,15 @@ def test_kernel_integrates_with_feasibility_counts():
     got = np.asarray(ranked_conv_op(jnp.asarray(Z), k))
     ref = np.asarray(ranked_conv_ref(jnp.asarray(Z), k))
     assert np.array_equal(got, ref)
+
+
+def test_interpret_mode_is_asked_for_never_inferred(monkeypatch):
+    """Interpret mode comes from the call or the process variable only;
+    the backend (a CPU here) never turns it on."""
+    from repro.kernels import ops
+    monkeypatch.delenv(ops.INTERPRET_ENV, raising=False)
+    assert ops._resolve(None) is False
+    assert ops._resolve(True) is True
+    monkeypatch.setenv(ops.INTERPRET_ENV, "1")
+    assert ops._resolve(None) is True
+    assert ops._resolve(False) is False

@@ -6,7 +6,7 @@ the O(3^n) oracles."""
 import numpy as np
 import pytest
 
-from repro.core import engine, jointree, lattice
+from repro.core import engine, f64bits, jointree, lattice
 from repro.core.baselines import dpsub
 from repro.core.bitset import popcounts
 from repro.core.ccap import ccap, ccap_batch
@@ -179,8 +179,8 @@ def test_minplus_value_layers_bitwise_vs_dpsub():
     for card in cards:
         for gamma in (np.inf, float(np.sort(card)[-3])):
             gate_ok = (card <= gamma) | (pc < 2)
-            dev = np.asarray(lattice.minplus_value_layers(
-                card[None, :], gate_ok[None, :], n))[0]
+            dev = f64bits.from_bits(lattice.minplus_value_layers(
+                f64bits.to_bits(card)[None, :], gate_ok[None, :], n))[0]
             ref = dpsub(card, n, mode="out",
                         prune_gamma=None if np.isinf(gamma) else gamma)
             assert np.array_equal(dev, ref)

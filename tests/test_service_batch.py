@@ -107,3 +107,14 @@ def test_grid_topology_plans():
     res = optimize(q, card, cost="max")
     assert res.tree.validate()
     assert res.tree.cost_max(card) == res.cost
+
+
+def test_solve_mesh_wider_than_devices_is_refused():
+    """The server lifts its cap/out ceilings for the policy's mesh width,
+    so a width the visible devices cannot hold is an error at
+    construction, never a silent single-device fallback."""
+    import jax
+    from repro.service import PlanServer
+    too_wide = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match="visible"):
+        PlanServer(batch_policy=BatchPolicy(solve_shards=too_wide))
