@@ -57,6 +57,7 @@ from repro.core import f64bits, jointree, lattice
 from repro.core.bitset import popcounts
 from repro.core.lattice import BACKENDS  # noqa: F401  (re-export)
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import phase
 
 
 # ----------------------------------------------------------------- telemetry
@@ -111,11 +112,13 @@ class DispatchRecord:
 
     The serving runtime marks the ring before handing work to the
     solver (``dispatch_mark``) and collects the records that landed
-    while it waited (``dispatches_since``), attributing compile/execute
-    split, while-loop rounds and XLA flops/bytes to the request spans
-    that were blocked on that dispatch.
+    while it waited (``dispatches_since``), attributing the compile /
+    prepare / execute / fetch split and while-loop rounds to the request
+    spans that were blocked on that dispatch.  ``seq`` is the id the
+    dispatch's ``plan.prepare`` / ``plan.execute`` / ``plan.fetch``
+    trace annotations carry.
     """
-    seq: int                   # monotone id (ring position survives wrap)
+    seq: int                   # monotone id, taken when the solve starts
     cost: str                  # "max" | "cap" | "cap_conn" | "out[_seeded]"
     n: int
     B: int                    # padded batch bucket
@@ -126,8 +129,8 @@ class DispatchRecord:
     compile_s: float           # 0.0 on a cache hit
     execute_s: float           # blocked-until-ready device wall time
     rounds: int = 0            # while-loop rounds (filled post-solve)
-    flops: "float | None" = None  # XLA cost analysis, whole program;
-    bytes_accessed: "float | None" = None  # None where XLA reports none
+    prepare_s: float = 0.0     # host: padding, bits, host-to-device copies
+    fetch_s: float = 0.0       # host: device-to-host copies, tree assembly
     shards: int = 1            # solve-mesh width (1 = single device)
     devices: tuple = ()        # mesh device ids ((platform, ids) pair)
     lane: "int | None" = None  # serving lane that issued the dispatch
@@ -141,7 +144,7 @@ class DispatchRecord:
 
 _STATS = EngineStats(obs_metrics.default_registry())
 _EXEC_CACHE: dict = {}
-_EXEC_META: dict = {}          # key -> {"compile_s", "flops", ...}
+_EXEC_META: dict = {}          # key -> {"compile_s", "module", ...}
 _PROFILE: collections.deque = collections.deque(maxlen=512)
 _PROFILE_LOCK = threading.Lock()
 _PROFILE_SEQ = 0
@@ -166,17 +169,22 @@ def dispatch_mark() -> int:
 
 
 def dispatches_since(mark: int) -> "list[DispatchRecord]":
-    """Profile records appended after ``mark`` (oldest first), as far
-    back as the ring still holds them."""
+    """Profile records of the dispatches begun after ``mark`` (in the
+    order they finished), as far back as the ring still holds them."""
     with _PROFILE_LOCK:
         return [r for r in _PROFILE if r.seq > mark]
 
 
-def _profile_append(rec: DispatchRecord) -> None:
+def _next_seq() -> int:
+    """The id of a dispatch about to be prepared."""
     global _PROFILE_SEQ
     with _PROFILE_LOCK:
         _PROFILE_SEQ += 1
-        rec.seq = _PROFILE_SEQ
+        return _PROFILE_SEQ
+
+
+def _profile_append(rec: DispatchRecord) -> None:
+    with _PROFILE_LOCK:
         _PROFILE.append(rec)
     h = _STATS.registry.histogram
     h("engine.execute_s").observe(rec.execute_s)
@@ -343,8 +351,9 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
                 extract: bool, cost: str, gamma_batch: int,
                 shards: int = 1):
     """Cache lookup + compile with profiling: returns ``(exe, meta,
-    hit)`` where ``meta`` carries the bucket key, one-time compile
-    seconds, XLA flops/bytes and the lattice program card."""
+    hit)`` where ``meta`` carries the bucket key, the compiled module's
+    name, one-time compile seconds, the memory footprint and the lattice
+    program card."""
     shards = max(1, int(shards))
     devs = _mesh_identity(shards)
     key = (n, B, C, backend, direct_layers, bool(extract), cost,
@@ -419,34 +428,44 @@ def _executable(n: int, B: int, C: int, backend: str, direct_layers: int,
         ]
     else:
         raise ValueError(f"unknown fused cost {cost!r}")
+
+    # each bucket's module gets a name of its own
+    # (``jit_max_n15_B2_C32768_pallas``): a device trace's "XLA Modules"
+    # line then says which executable each launch ran
+    def program(*a):
+        return fn(*a)
+    program.__name__ = program.__qualname__ = _program_name(
+        n, B, C, backend, direct_layers, extract, cost, gamma_batch, shards)
     with _TRACE_LOCK:
-        lowered = jax.jit(fn).lower(*args)
+        lowered = jax.jit(program).lower(*args)
     exe = lowered.compile()
-    meta = {"key": key, "shards": shards, "devices": devs,
+    meta = {"key": key, "module": "jit_" + program.__name__,
+            "shards": shards, "devices": devs,
             # timing: measured-duration (AOT compile)
             "compile_s": time.perf_counter() - t0,
             "program": lattice.program_card(n, cost, backend=backend,
                                             gamma_batch=gamma_batch,
                                             extract=bool(extract),
                                             shards=shards),
-            **_cost_analysis(exe), "memory": _memory_analysis(exe)}
+            "memory": _memory_analysis(exe)}
     _EXEC_CACHE[key] = exe
     _EXEC_META[key] = meta
     return exe, meta, False
 
 
-def _cost_analysis(exe) -> dict:
-    """XLA's whole-program ``flops`` and ``bytes_accessed`` for one
-    executable.  A figure XLA does not report is None, never 0, so a
-    reader cannot mistake a missing analysis for a free program."""
-    try:
-        ca = exe.cost_analysis() or {}
-    except (NotImplementedError, jax.errors.JaxRuntimeError):
-        ca = {}
-
-    def get(k):
-        return float(ca[k]) if ca.get(k) is not None else None
-    return {"flops": get("flops"), "bytes_accessed": get("bytes accessed")}
+def _program_name(n: int, B: int, C: int, backend: str,
+                  direct_layers: int, extract: bool, cost: str,
+                  gamma_batch: int, shards: int) -> str:
+    """The function name of one bucket's program, from the parts of its
+    cache key that can differ within a process."""
+    name = f"{cost}_n{n}_B{B}_C{C}_{backend}"
+    if direct_layers != 4:
+        name += f"_dl{direct_layers}"
+    if gamma_batch != 1:
+        name += f"_G{gamma_batch}"
+    if shards != 1:
+        name += f"_s{shards}"
+    return name if extract else name + "_noextract"
 
 
 def _memory_analysis(exe) -> "dict | None":
@@ -479,10 +498,19 @@ def use_compile_cache(root: str) -> str:
 
 
 def compiled_buckets() -> "list[dict]":
-    """One dict per compiled executable: its bucket ``key``, compile
-    seconds, XLA cost figures, ``memory`` bytes and lattice program
-    card."""
+    """One dict per compiled executable: its bucket ``key``, ``module``
+    name, compile seconds, ``memory`` bytes and lattice program card."""
     return [dict(m) for m in list(_EXEC_META.values())]
+
+
+def compiled_hlo_texts() -> "dict[str, str]":
+    """``{module name: optimized HLO text}`` of every compiled
+    executable.  The text holds each instruction's ``op_name`` metadata,
+    which carries the lattice programs' ``search`` and ``extract``
+    scopes; a device trace names an op by its instruction alone.  Built
+    when asked, never on the serving path."""
+    return {_EXEC_META[k]["module"]: exe.as_text()
+            for k, exe in list(_EXEC_CACHE.items())}
 
 
 def candidate_bucket(n: int) -> int:
@@ -546,35 +574,31 @@ def compile_jobs(jobs: list) -> dict:
 
 
 # -------------------------------------------------------------- entry point
-def _run(exe, *args, record: "DispatchRecord | None" = None):
+def _run(exe, *args, record: DispatchRecord):
     """The single device-execution site: every XLA invocation the engine
     ever makes goes through here, so ``stats().dispatches`` is a real
     execution count (the dispatches-per-solve acceptance check would
     catch a future change that sneaks in a second call per solve).
 
-    With a ``record``, the call blocks until the outputs are ready so
-    ``execute_s`` is real device wall time (the fused solvers consume
-    the outputs on the host immediately anyway), and the record lands
-    in the profile ring.
+    The call blocks until the outputs are ready, so ``execute_s`` is
+    real device wall time (the fused solvers consume the outputs on the
+    host immediately anyway), and the record lands in the profile ring.
     """
     _STATS.inc("dispatches")
-    t0 = time.perf_counter()  # timing: measured-duration (execute wall)
-    out = exe(*args)
-    if record is not None:
-        jax.block_until_ready(out)
-        record.execute_s = time.perf_counter() - t0  # timing: measured-duration
-        _profile_append(record)
+    with phase("execute", dispatch=record.seq) as ex:
+        out = jax.block_until_ready(exe(*args))
+    record.execute_s = ex.seconds
+    _profile_append(record)
     return out
 
 
-def _record(cost: str, n: int, Bp: int, C: int, backend: str,
-            meta: dict, hit: bool) -> DispatchRecord:
-    return DispatchRecord(seq=0, cost=cost, n=n, B=Bp, C=C,
+def _record(seq: int, cost: str, n: int, Bp: int, C: int, backend: str,
+            meta: dict, hit: bool, prepare_s: float) -> DispatchRecord:
+    return DispatchRecord(seq=seq, cost=cost, n=n, B=Bp, C=C,
                           backend=backend, key=meta["key"],
                           aot_cache_hit=hit,
                           compile_s=0.0 if hit else meta["compile_s"],
-                          execute_s=0.0, flops=meta["flops"],
-                          bytes_accessed=meta["bytes_accessed"],
+                          execute_s=0.0, prepare_s=prepare_s,
                           shards=meta.get("shards", 1),
                           devices=meta.get("devices", ()),
                           lane=current_lane())
@@ -683,29 +707,35 @@ def fused_dpconv_max(cards: np.ndarray, n: int, direct_layers: int = 4,
     B, size = cards.shape
     assert size == 1 << n and n >= 2
     assert gamma_batch >= 1
-    cards_pad, cand_pad, hi0, Bp, C = _pad_candidates(cards, n)
-    lo0, hi0, seeded = _seed_bracket(cand_pad, hi0, seed_opt, B)
+    seq = _next_seq()
+    with phase("prepare", dispatch=seq) as prep:
+        cards_pad, cand_pad, hi0, Bp, C = _pad_candidates(cards, n)
+        lo0, hi0, seeded = _seed_bracket(cand_pad, hi0, seed_opt, B)
+        args = (jnp.asarray(f64bits.to_bits(cards_pad)),
+                jnp.asarray(f64bits.to_bits(cand_pad)),
+                jnp.asarray(lo0), jnp.asarray(hi0))
 
     cost = "max_seeded" if seeded else "max"
     exe, emeta, hit = _executable(n, Bp, C, backend, direct_layers,
                                   extract_tree, cost, gamma_batch,
                                   shards)
-    prof = _record(cost, n, Bp, C, backend, emeta, hit)
+    prof = _record(seq, cost, n, Bp, C, backend, emeta, hit, prep.seconds)
     disp0 = _STATS.dispatches
     rec0 = jointree.recursive_extractions()
-    out = _run(exe, jnp.asarray(f64bits.to_bits(cards_pad)),
-               jnp.asarray(f64bits.to_bits(cand_pad)),
-               jnp.asarray(lo0), jnp.asarray(hi0), record=prof)
+    out = _run(exe, *args, record=prof)
     trees: list = [None] * B
     dpn = None
-    if extract_tree:
-        opt, dp, nodes, lidx, rounds = out
-        dpn = np.asarray(dp, np.float64)[:B]
-        trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx), B)
-    else:
-        opt, rounds = out
-    opt = f64bits.from_bits(opt)[:B]
-    rounds = int(rounds)
+    with phase("fetch", dispatch=seq) as fetch:
+        if extract_tree:
+            opt, dp, nodes, lidx, rounds = out
+            dpn = np.asarray(dp, np.float64)[:B]
+            trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx),
+                                       B)
+        else:
+            opt, rounds = out
+        opt = f64bits.from_bits(opt)[:B]
+        rounds = int(rounds)
+    prof.fetch_s = fetch.seconds
     prof.rounds = rounds
 
     # the "zero per-solve host recursions" invariant: tree assembly must
@@ -753,52 +783,58 @@ def fused_out(qs: list, cards: np.ndarray, n: int,
     B, size = cards.shape
     assert size == 1 << n and n >= 2
     assert len(qs) == B
-    conn = np.stack([connectivity_masks(q) for q in qs])
-    if not conn[:, -1].all():
-        raise ValueError("fused_out requires connected query graphs "
-                         "(DPccp excludes cross products); route "
-                         "disconnected queries to the full-lattice "
-                         "pipelines")
-    Bp = _next_pow2(B)
-    cards_pad, conn_pad = cards, conn
-    if Bp != B:
-        cards_pad = np.concatenate(
-            [cards, np.repeat(cards[:1], Bp - B, axis=0)], axis=0)
-        conn_pad = np.concatenate(
-            [conn, np.repeat(conn[:1], Bp - B, axis=0)], axis=0)
+    seq = _next_seq()
+    with phase("prepare", dispatch=seq) as prep:
+        conn = np.stack([connectivity_masks(q) for q in qs])
+        if not conn[:, -1].all():
+            raise ValueError("fused_out requires connected query graphs "
+                             "(DPccp excludes cross products); route "
+                             "disconnected queries to the full-lattice "
+                             "pipelines")
+        Bp = _next_pow2(B)
+        cards_pad, conn_pad = cards, conn
+        if Bp != B:
+            cards_pad = np.concatenate(
+                [cards, np.repeat(cards[:1], Bp - B, axis=0)], axis=0)
+            conn_pad = np.concatenate(
+                [conn, np.repeat(conn[:1], Bp - B, axis=0)], axis=0)
 
-    seeded = 0
-    cost = "out"
-    extra = ()
-    if seed_ok is not None and np.any(seed_ok):
-        sv = np.zeros((Bp, size), np.float64)
-        so = np.zeros((Bp, size), bool)
-        sv[:B] = np.asarray(seed_vals, np.float64)
-        so[:B] = np.asarray(seed_ok, bool)
-        seeded = int(np.count_nonzero(so[:B].any(axis=1)))
-        cost = "out_seeded"
-        extra = (jnp.asarray(f64bits.to_bits(sv)), jnp.asarray(so))
+        seeded = 0
+        cost = "out"
+        args = (jnp.asarray(f64bits.to_bits(cards_pad)),
+                jnp.asarray(conn_pad))
+        if seed_ok is not None and np.any(seed_ok):
+            sv = np.zeros((Bp, size), np.float64)
+            so = np.zeros((Bp, size), bool)
+            sv[:B] = np.asarray(seed_vals, np.float64)
+            so[:B] = np.asarray(seed_ok, bool)
+            seeded = int(np.count_nonzero(so[:B].any(axis=1)))
+            cost = "out_seeded"
+            args += (jnp.asarray(f64bits.to_bits(sv)), jnp.asarray(so))
 
     exe, emeta, hit = _executable(n, Bp, 0, "xla", 4, extract_tree,
                                   cost, 1, shards)
-    prof = _record(cost, n, Bp, 0, "xla", emeta, hit)
+    prof = _record(seq, cost, n, Bp, 0, "xla", emeta, hit, prep.seconds)
     disp0 = _STATS.dispatches
     rec0 = jointree.recursive_extractions()
-    out = _run(exe, jnp.asarray(f64bits.to_bits(cards_pad)),
-               jnp.asarray(conn_pad), *extra, record=prof)
+    out = _run(exe, *args, record=prof)
     trees: list = [None] * B
     dpn = None
-    if extract_tree:
-        cout, dp, nodes, lidx = out
-        dpn = f64bits.from_bits(dp)[:B]
-        trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx), B)
-    else:
-        (cout,) = out
+    with phase("fetch", dispatch=seq) as fetch:
+        if extract_tree:
+            cout, dp, nodes, lidx = out
+            dpn = f64bits.from_bits(dp)[:B]
+            trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx),
+                                       B)
+        else:
+            (cout,) = out
+        couts = f64bits.from_bits(cout)[:B]
+    prof.fetch_s = fetch.seconds
     _STATS.inc("host_extractions",
                jointree.recursive_extractions() - rec0)
     _STATS.inc("solves")
     _STATS.inc("queries", B)
-    return FusedOutSolve(couts=f64bits.from_bits(cout)[:B],
+    return FusedOutSolve(couts=couts,
                          trees=trees,
                          dispatches=_STATS.dispatches - disp0,
                          dp=dpn, extraction="device", seeded=seeded)
@@ -838,52 +874,59 @@ def fused_ccap(cards: np.ndarray, n: int, gamma_slack: float = 1.0,
         cards = cards[None, :]
     B, size = cards.shape
     assert size == 1 << n and n >= 2
-    cards_pad, cand_pad, hi0, Bp, C = _pad_candidates(cards, n)
-    lo0, hi0, seeded = _seed_bracket(cand_pad, hi0, seed_opt, B)
+    seq = _next_seq()
+    with phase("prepare", dispatch=seq) as prep:
+        cards_pad, cand_pad, hi0, Bp, C = _pad_candidates(cards, n)
+        lo0, hi0, seeded = _seed_bracket(cand_pad, hi0, seed_opt, B)
+        caps = cand_pad * np.float64(gamma_slack)  # host f64, exact IEEE
+        args = (jnp.asarray(f64bits.to_bits(cards_pad)),
+                jnp.asarray(f64bits.to_bits(cand_pad)),
+                jnp.asarray(lo0), jnp.asarray(hi0),
+                jnp.asarray(f64bits.to_bits(caps)))
+        cost = "cap"
+        if qs is not None:
+            from repro.core.dpccp import connectivity_masks
 
-    extra = ()
-    cost = "cap"
-    if qs is not None:
-        from repro.core.dpccp import connectivity_masks
-
-        assert len(qs) == B
-        conn = np.stack([connectivity_masks(q) for q in qs])
-        if not conn[:, -1].all():
-            raise ValueError("the connected C_cap pass requires "
-                             "connected query graphs (DPccp excludes "
-                             "cross products)")
-        conn_pad = conn if Bp == B else np.concatenate(
-            [conn, np.repeat(conn[:1], Bp - B, axis=0)], axis=0)
-        extra = (jnp.asarray(conn_pad),)
-        cost = "cap_conn"
-    if seeded:
-        cost += "_seeded"
+            assert len(qs) == B
+            conn = np.stack([connectivity_masks(q) for q in qs])
+            if not conn[:, -1].all():
+                raise ValueError("the connected C_cap pass requires "
+                                 "connected query graphs (DPccp excludes "
+                                 "cross products)")
+            conn_pad = conn if Bp == B else np.concatenate(
+                [conn, np.repeat(conn[:1], Bp - B, axis=0)], axis=0)
+            args += (jnp.asarray(conn_pad),)
+            cost = "cap_conn"
+        if seeded:
+            cost += "_seeded"
 
     exe, emeta, hit = _executable(n, Bp, C, backend, direct_layers,
                                   extract_tree, cost, gamma_batch,
                                   shards)
-    prof = _record(cost, n, Bp, C, backend, emeta, hit)
+    prof = _record(seq, cost, n, Bp, C, backend, emeta, hit, prep.seconds)
     disp0 = _STATS.dispatches
     rec0 = jointree.recursive_extractions()
-    caps = cand_pad * np.float64(gamma_slack)     # host f64, exact IEEE
-    out = _run(exe, jnp.asarray(f64bits.to_bits(cards_pad)),
-               jnp.asarray(f64bits.to_bits(cand_pad)),
-               jnp.asarray(lo0), jnp.asarray(hi0),
-               jnp.asarray(f64bits.to_bits(caps)), *extra, record=prof)
+    out = _run(exe, *args, record=prof)
     trees = [None] * B
-    if extract_tree:
-        gamma, cout, nodes, lidx, rounds = out
-        trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx), B)
-    else:
-        gamma, cout, rounds = out
-    prof.rounds = int(rounds)
+    with phase("fetch", dispatch=seq) as fetch:
+        if extract_tree:
+            gamma, cout, nodes, lidx, rounds = out
+            trees = _trees_from_arrays(np.asarray(nodes), np.asarray(lidx),
+                                       B)
+        else:
+            gamma, cout, rounds = out
+        rounds = int(rounds)
+        gammas = f64bits.from_bits(gamma)[:B]
+        couts = f64bits.from_bits(cout)[:B]
+    prof.fetch_s = fetch.seconds
+    prof.rounds = rounds
     _STATS.inc("host_extractions",
                jointree.recursive_extractions() - rec0)
     _STATS.inc("solves")
     _STATS.inc("queries", B)
-    _STATS.inc("rounds", int(rounds))
-    return FusedCapSolve(gammas=f64bits.from_bits(gamma)[:B],
-                         couts=f64bits.from_bits(cout)[:B],
-                         trees=trees, rounds=int(rounds),
+    _STATS.inc("rounds", rounds)
+    return FusedCapSolve(gammas=gammas,
+                         couts=couts,
+                         trees=trees, rounds=rounds,
                          dispatches=_STATS.dispatches - disp0,
                          extraction="device", seeded=seeded)

@@ -38,7 +38,13 @@ slots, carried ranked-zeta buffer: the fused engine's mode).
 
 ``build_max_program`` / ``build_cap_program`` / ``build_out_program``
 compose the axes into whole-solve programs — one dispatch per batched
-solve — that ``repro.core.engine`` AOT-compiles and caches.  Exactness notes sit
+solve — that ``repro.core.engine`` AOT-compiles and caches.  Each names
+its device phases with ``jax.named_scope``: ``search`` (the gate builder
+and the threshold search loop; the out program's (min,+) sweep, which
+finds its optimum) and ``extract`` (the pass at the optimum that builds
+the table extraction reads, and the extraction scan).  The scopes reach
+the compiled instructions' ``op_name`` metadata, so a device trace's ops
+can be told apart by phase; no instruction changes.  Exactness notes sit
 next to each piece; every instantiation is bit-identical to its host
 reference (asserted by tests/test_lattice_parity.py).
 """
@@ -47,6 +53,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -724,7 +731,6 @@ def _shard_wrap(fn, mesh):
     can't see through the scatter/while_loop combines, but every output
     is replicated by construction (each layer ends in a mesh-wide
     ``psum``)."""
-    import jax
     from jax.sharding import PartitionSpec
     P = PartitionSpec()
     return jax.shard_map(fn, mesh=mesh, in_specs=P, out_specs=P,
@@ -763,24 +769,26 @@ def build_max_program(n: int, direct_layers: int, backend: str,
 
     def fn(cards, cand, lo0, hi0):
         pc = jnp.asarray(pc_np, dtype=jnp.int32)
-        gate_of = _gate_builder(cards, pc, tfm.dtype)
-        hi, Z, rounds = _fused_search(cards, cand, lo0, hi0, n,
-                                      direct_layers, tfm, G, gate_of,
-                                      shards=shards, shard_axis=axis,
-                                      verify_seed=seeded)
-        opt = jnp.take_along_axis(cand, hi[:, None], axis=1)[:, 0]
+        with jax.named_scope("search"):
+            gate_of = _gate_builder(cards, pc, tfm.dtype)
+            hi, Z, rounds = _fused_search(cards, cand, lo0, hi0, n,
+                                          direct_layers, tfm, G, gate_of,
+                                          shards=shards, shard_axis=axis,
+                                          verify_seed=seeded)
+            opt = jnp.take_along_axis(cand, hi[:, None], axis=1)[:, 0]
         if not extract:
             return opt, rounds
         # extraction pass: full final layer at the optimum's gate.  For
         # G > 1 the probe axis is dropped — slice 0 of the carried buffer
         # keeps the (round-invariant) singleton transform in slot 1, and
         # every slot >= 2 is rewritten before the recursion reads it.
-        Zx = Z if G == 1 else Z[:, 0]
-        dp, _, _ = feasibility_layers(gate_of(opt), n, dl, tfm, False,
-                                      Z=Zx, scan_middle=True,
-                                      shards=shards, shard_axis=axis)
-        dp = dp.astype(jnp.int32)                  # {0,1}
-        nodes, lidx = extract_scan(dp, n)
+        with jax.named_scope("extract"):
+            Zx = Z if G == 1 else Z[:, 0]
+            dp, _, _ = feasibility_layers(gate_of(opt), n, dl, tfm, False,
+                                          Z=Zx, scan_middle=True,
+                                          shards=shards, shard_axis=axis)
+            dp = dp.astype(jnp.int32)              # {0,1}
+            nodes, lidx = extract_scan(dp, n)
         return opt, dp, nodes, lidx, rounds
 
     return _shard_wrap(fn, mesh) if axis is not None else fn
@@ -814,14 +822,16 @@ def build_out_program(n: int, extract: bool, shards: int = 1,
     axis = _solve_axis(shards, mesh)
 
     def body(cards, conn, seed_vals=None, seed_ok=None):
-        dpv = minplus_connected_layers(cards, conn, n, shards=shards,
-                                       shard_axis=axis,
-                                       seed_vals=seed_vals,
-                                       seed_ok=seed_ok)
+        with jax.named_scope("search"):
+            dpv = minplus_connected_layers(cards, conn, n, shards=shards,
+                                           shard_axis=axis,
+                                           seed_vals=seed_vals,
+                                           seed_ok=seed_ok)
         cout = dpv[..., -1]
         if not extract:
             return (cout,)
-        nodes, lidx = extract_scan(dpv, n, card=cards)
+        with jax.named_scope("extract"):
+            nodes, lidx = extract_scan(dpv, n, card=cards)
         return cout, dpv, nodes, lidx
 
     if seeded:                          # fixed arity for shard_map specs
@@ -866,23 +876,26 @@ def build_cap_program(n: int, direct_layers: int, backend: str,
 
     def fn(cards, cand, lo0, hi0, caps, conn=None):
         pc = jnp.asarray(pc_np, dtype=jnp.int32)
-        gate_of = _gate_builder(cards, pc, tfm.dtype)
-        hi, _, rounds = _fused_search(cards, cand, lo0, hi0, n,
-                                      direct_layers, tfm, G, gate_of,
-                                      shards=shards, shard_axis=axis,
-                                      verify_seed=seeded)
-        gamma = jnp.take_along_axis(caps, hi[:, None], axis=1)[:, 0]
-        gate_ok = (cards <= gamma[:, None]) | (pc < 2)
-        if connected:
-            dpv = minplus_connected_layers(cards, gate_ok & conn, n,
+        with jax.named_scope("search"):
+            gate_of = _gate_builder(cards, pc, tfm.dtype)
+            hi, _, rounds = _fused_search(cards, cand, lo0, hi0, n,
+                                          direct_layers, tfm, G, gate_of,
+                                          shards=shards, shard_axis=axis,
+                                          verify_seed=seeded)
+            gamma = jnp.take_along_axis(caps, hi[:, None], axis=1)[:, 0]
+        with jax.named_scope("extract"):
+            gate_ok = (cards <= gamma[:, None]) | (pc < 2)
+            if connected:
+                dpv = minplus_connected_layers(cards, gate_ok & conn, n,
+                                               shards=shards,
+                                               shard_axis=axis)
+            else:
+                dpv = minplus_value_layers(cards, gate_ok, n,
                                            shards=shards, shard_axis=axis)
-        else:
-            dpv = minplus_value_layers(cards, gate_ok, n, shards=shards,
-                                       shard_axis=axis)
-        cout = dpv[..., -1]
-        if not extract:
-            return gamma, cout, rounds
-        nodes, lidx = extract_scan(dpv, n, card=cards)
+            cout = dpv[..., -1]
+            if not extract:
+                return gamma, cout, rounds
+            nodes, lidx = extract_scan(dpv, n, card=cards)
         return gamma, cout, nodes, lidx, rounds
 
     if axis is None:

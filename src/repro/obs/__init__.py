@@ -9,12 +9,14 @@ objects; this package gives them one story:
   serving layer registers into.  The old ``stats()`` / ``as_dict()``
   objects survive as thin views over registry instruments or as
   registered snapshot providers, so nothing downstream breaks.
-* ``trace``    — zero-dependency structured span tracing.  A ``Tracer``
-  produces one span tree per request (admit -> queue_wait -> dispatch
-  -> extract -> respond, with coalesce / fast_path / shed variants),
-  reading time ONLY through the runtime's ``Clock`` abstraction — span
-  trees are bit-deterministic on a ``VirtualClock`` and tests assert
-  their exact shapes.
+* ``trace``    — structured span tracing.  A ``Tracer`` produces one
+  span tree per request (admit -> queue_wait -> dispatch -> extract ->
+  respond, with coalesce / fast_path / shed variants), reading time
+  ONLY through the runtime's ``Clock`` abstraction — span trees are
+  bit-deterministic on a ``VirtualClock`` and tests assert their exact
+  shapes.  ``phase`` puts one block of host work (admit, canonicalize,
+  probe, close_bucket, finalize; the engine's prepare, execute, fetch)
+  on the profiler's clock as a ``plan.<name>`` ``TraceAnnotation``.
 * ``recorder`` — the flight recorder: a bounded ring buffer of
   completed span trees plus an always-on capture of every shed /
   downgraded / deadline-missed request, dumpable as JSON lines.
@@ -25,11 +27,13 @@ objects; this package gives them one story:
 Wiring: ``PlanServer`` owns a ``MetricsRegistry``; ``ServingRuntime``
 owns a ``Tracer`` + ``FlightRecorder`` bound to that registry and its
 clock; ``repro.core.engine`` emits per-dispatch profiling records
-(AOT-cache hit/miss, compile-vs-execute split, while-loop rounds,
-bucket key, XLA flops/bytes) that the runtime attributes to the spans
-that waited on each dispatch.  ``scripts/smoke.sh`` gates on the
-resulting telemetry (zero unclosed spans, per-lane span shapes, exact
-shed/missed capture, tracing overhead) via serve_bench's ``obs`` row.
+(AOT-cache hit/miss, compile / prepare / execute / fetch split,
+while-loop rounds, bucket key) that the runtime attributes to the spans
+that waited on each dispatch, and every traced response carries its
+phases' seconds in ``PlanResponse.timing_s``.  ``scripts/smoke.sh`` gates
+on the resulting telemetry (zero unclosed spans, per-lane span shapes,
+exact shed/missed capture, tracing overhead) via serve_bench's ``obs``
+row.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry, default_registry)

@@ -1,4 +1,4 @@
-"""Structured span tracing for the serving stack — zero dependencies.
+"""Structured span tracing for the serving stack.
 
 A ``Span`` is a named interval with attributes and children; a
 ``Tracer`` mints one root span per request and the runtime hangs phase
@@ -6,11 +6,14 @@ spans off it as the request moves through its lane:
 
     request
       admit                     admission control: probe, reroute, charge
+        canonicalize            canonical labelling of the query
+        probe                   the primary route's plan-cache probe
       queue_wait                enqueue -> batch dispatch      (miss lane)
       coalesce                  joined an identical in-flight request
       fast_path                 cache hit served inline
-      dispatch                  solver work: compile|execute split,
-                                while-loop rounds, engine tag, flops
+      dispatch                  solver work: compile|prepare|execute|
+                                fetch split, while-loop rounds, engine
+                                tag, the DispatchRecord ids
       extract                   tree reconstruction + cache insert
       respond                   completion bookkeeping
       shed                      refused: deadline / backpressure / error
@@ -23,8 +26,52 @@ the per-phase p50/p95 breakdown that serve_bench's ``obs`` row reports.
 
 Disabled tracing costs one attribute check per call site: ``Tracer``
 hands out the shared ``NULL_SPAN``, whose every method is a no-op.
+
+``phase`` puts one synchronous block of host work on the profiler's
+clock as well: a ``jax.profiler.TraceAnnotation`` named ``plan.<name>``
+lands in the same trace as the device's operations, so a device idle gap
+can be named by the program phase the host was in.
 """
 from __future__ import annotations
+
+import time
+
+from jax import profiler
+
+
+class phase:
+    """``with phase("canonicalize", admit_span, req_id=7) as p: ...``
+
+    Around one synchronous block of host work on one thread: a
+    ``plan.<name>`` ``TraceAnnotation`` whose metadata carries ``ids``
+    (``req_id`` for a request's phases; ``dispatch``, the
+    ``DispatchRecord.seq``, for a dispatch's), and, under a parent span,
+    the child span ``name`` on the tracer's clock, whose close feeds
+    ``trace.<name>_s``.  ``p.seconds`` is the block's wall time
+    afterwards, for the ``DispatchRecord`` fields.  With the profiler
+    off the annotation records nothing; the helper adds two clock reads
+    and, under a live span, the child span.
+    """
+
+    __slots__ = ("_ann", "_span", "_t0", "seconds")
+
+    def __init__(self, name: str, parent=None, **ids):
+        self._ann = profiler.TraceAnnotation("plan." + name, **ids)
+        self._span = None if parent is None else parent.child(name)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()  # timing: measured-duration
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        # timing: measured-duration (the block's own wall time)
+        self.seconds = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.close()
+        self._ann.__exit__(*exc)
+        return False
 
 
 class Span:

@@ -78,7 +78,7 @@ import numpy as np
 
 from repro.core import engine as engine_mod
 from repro.obs.recorder import FlightRecorder
-from repro.obs.trace import NULL_SPAN, Tracer
+from repro.obs.trace import NULL_SPAN, Tracer, phase
 from repro.service.cache import PlanCache
 from repro.service import faults as faults_mod
 from repro.service import router as router_mod
@@ -321,6 +321,7 @@ class Ticket:
     queued: bool = False                # sat in a forming bucket
     coalesced_join: bool = False        # joined another entry's solve
     dispatched: bool = False            # a dispatch span was opened
+    admit_phases: int = 0               # phase spans opened under admit
     price_est: float = 0.0              # router's solve estimate at start
     # --- resilience: the response contract and its provenance
     status: str = "exact"               # "exact" | "degraded" | "error"
@@ -393,11 +394,26 @@ class _Work:
         self.layer_seeds = 0             # items warm-started by layercache
 
 
-def _total(values) -> "float | None":
-    """Sum of per-dispatch cost figures; None if any dispatch has none
-    (a partial sum would understate the work)."""
-    values = list(values)
-    return None if any(v is None for v in values) else sum(values)
+def _timing(root) -> dict:
+    """``PlanResponse.timing_s`` of one finished request tree: the
+    seconds of its admit span and admit's phase children, of its queue
+    wait, and its dispatch's prepare / execute / fetch (the dispatch's
+    own, shared by its batch; a retried request reports its last
+    attempt)."""
+    out = {}
+    for s in root.children:
+        if s.name == "admit":
+            out["admit"] = s.duration
+            for c in s.children:
+                out[c.name] = c.duration
+        elif s.name == "queue_wait":
+            out["queue_wait"] = s.duration
+        elif s.name == "dispatch":
+            for k in ("prepare", "execute", "fetch"):
+                v = s.attrs.get(k + "_s")
+                if v is not None:
+                    out[k] = v
+    return out
 
 
 # ------------------------------------------------------------------ runtime
@@ -612,13 +628,15 @@ class ServingRuntime:
         tracer compares against the actual tree (shape self-check).
         fast path: request/admit/fast_path/respond.  Miss: request +
         admit + optional queue_wait + optional coalesce + dispatch,
-        then extract+respond (served) or shed (refused).  Retried and
-        failed-over solves open one extra dispatch span per additional
-        attempt (``ticket.extra_spans``)."""
+        then extract+respond (served) or shed (refused).  Each adds the
+        phase spans admission opened under admit (canonicalize, probe).
+        Retried and failed-over solves open one extra dispatch span per
+        additional attempt (``ticket.extra_spans``)."""
         if fast:
-            return 4
-        n = (2 + ticket.queued + ticket.coalesced_join
-             + ticket.dispatched + ticket.extra_spans)
+            return 4 + ticket.admit_phases
+        n = (2 + ticket.admit_phases + ticket.queued
+             + ticket.coalesced_join + ticket.dispatched
+             + ticket.extra_spans)
         return n + (1 if refused else 2)
 
     # ------------------------------------------------------------- submit
@@ -626,19 +644,22 @@ class ServingRuntime:
         """Admit one request at ``clock.now()``: fast-path answer,
         coalesce, enqueue, downgrade or refuse.  Never blocks on a
         solve."""
+        with phase("admit", req_id=req.req_id):
+            return self._admit(req)
+
+    def _admit(self, req) -> Ticket:
         srv = self.server
         now = self.clock.now()
         t_wall = time.perf_counter()   # timing: measured-duration (admit)
         self.stats.submitted += 1
 
         card = np.asarray(req.card, np.float64)
-        form = canonicalize(req.q, card)
         slo = None
         if getattr(req, "slo", None):
             slo = self.config.slo_classes.get(req.slo)
             if slo is None:
                 raise ValueError(f"unknown SLO class {req.slo!r}")
-        ticket = Ticket(request=req, form=form, submitted=now,
+        ticket = Ticket(request=req, form=None, submitted=now,
                         slo=slo.name if slo else "default")
         span_attrs = {}
         tenant = getattr(req, "tenant", None)
@@ -649,8 +670,11 @@ class ServingRuntime:
             span_attrs["replica"] = replica
         ticket.span = self.tracer.request(
             at=now, req_id=req.req_id, slo=ticket.slo, cost=req.cost,
-            n=form.q.n, **span_attrs)
-        ticket.spans["admit"] = ticket.span.child("admit", at=now)
+            n=req.q.n, **span_attrs)
+        admit = ticket.spans["admit"] = ticket.span.child("admit", at=now)
+        with phase("canonicalize", admit, req_id=req.req_id):
+            form = ticket.form = canonicalize(req.q, card)
+        ticket.admit_phases = 1
         budget = req.latency_budget
         if budget is None and slo is not None:
             budget = slo.budget_s
@@ -685,16 +709,18 @@ class ServingRuntime:
         # ~zero time, overtaking any in-flight miss.  An injected cache
         # backend error fails OPEN: it degrades to a miss (the solve
         # path still answers), never to a request failure.
-        if (self.injector is not None
-                and self.injector.arm("cache") is not None):
-            self.fstats.cache_faults += 1
-            ticket.faulted = True
-            primary = srv.router.route(
-                form.q, req.cost, None, signature=form.signature,
-                connected=req.connected)
-            resp = None
-        else:
-            primary, resp = srv._primary_probe(req, form)
+        with phase("probe", admit, req_id=req.req_id):
+            if (self.injector is not None
+                    and self.injector.arm("cache") is not None):
+                self.fstats.cache_faults += 1
+                ticket.faulted = True
+                primary = srv.router.route(
+                    form.q, req.cost, None, signature=form.signature,
+                    connected=req.connected)
+                resp = None
+            else:
+                primary, resp = srv._primary_probe(req, form)
+        ticket.admit_phases = 2
         ticket.route = primary
         if resp is not None:
             self._finish_ticket(
@@ -960,22 +986,23 @@ class ServingRuntime:
         bucket = self._buckets.pop(nc, None)
         if bucket is None or not bucket.entries:
             return
-        n, cost = nc
-        entries = bucket.entries
-        self.stats.batches += 1
-        self.stats.batched_items += len(entries)
-        work = _Work("batch", entries, self.clock.now())
-        # the 5th item slot is the layer-cache seed payload: solved
-        # fragments of isomorphic sub-problems warm-start the lattice
-        # program (bit-identical results, fewer search rounds)
-        items = [(e.tickets[0].form.q, e.tickets[0].form.card,
-                  cost,
-                  router_mod.topo_class(e.tickets[0].form.signature),
-                  self.server._layer_seed(e.tickets[0].form,
-                                          e.tickets[0].request.cost,
-                                          e.tickets[0].route))
-                 for e in entries]
-        work.layer_seeds = sum(1 for it in items if it[4] is not None)
+        with phase("close_bucket"):
+            n, cost = nc
+            entries = bucket.entries
+            self.stats.batches += 1
+            self.stats.batched_items += len(entries)
+            work = _Work("batch", entries, self.clock.now())
+            # the 5th item slot is the layer-cache seed payload: solved
+            # fragments of isomorphic sub-problems warm-start the lattice
+            # program (bit-identical results, fewer search rounds)
+            items = [(e.tickets[0].form.q, e.tickets[0].form.card,
+                      cost,
+                      router_mod.topo_class(e.tickets[0].form.signature),
+                      self.server._layer_seed(e.tickets[0].form,
+                                              e.tickets[0].request.cost,
+                                              e.tickets[0].route))
+                     for e in entries]
+            work.layer_seeds = sum(1 for it in items if it[4] is not None)
         self._start(work, items)
 
     def _start_single(self, ticket: Ticket, engine: "str | None" = None,
@@ -1143,7 +1170,8 @@ class ServingRuntime:
         except BaseException as e:       # noqa: BLE001 — contained: the
             work.error = e               # failure ladder reroutes per entry
         # attribute the engine's per-dispatch profile records (AOT
-        # cache hit, compile/execute split, rounds, flops) to this work
+        # cache hit, compile / prepare / execute / fetch split, rounds)
+        # to this work
         work.profile = engine_mod.dispatches_since(mark)
         return time.perf_counter() - t0  # timing: measured-duration
 
@@ -1192,8 +1220,9 @@ class ServingRuntime:
     # -------------------------------------------------------- completion
     def _dispatch_attrs(self, work: _Work) -> dict:
         """Aggregate the work's attributed engine DispatchRecords into
-        the dispatch span's attributes (tentpole c: compile/execute
-        split, rounds, AOT cache hits, flops — per request)."""
+        the dispatch span's attributes (compile / prepare / execute /
+        fetch split, rounds, AOT cache hits, and the records' ids, which
+        the engine's ``plan.*`` trace annotations carry)."""
         lead = work.entries[0].tickets[0]
         attrs = {"engine_tag": self.server.router.engine_tag(
                      lead.route.method, lead.form.q.n, lead.route.lane,
@@ -1210,15 +1239,23 @@ class ServingRuntime:
         if prof:
             attrs.update(
                 dispatches=len(prof),
+                dispatch_ids=tuple(r.seq for r in prof),
                 aot_cache_hits=sum(r.aot_cache_hit for r in prof),
                 compile_s=sum(r.compile_s for r in prof),
+                prepare_s=sum(r.prepare_s for r in prof),
                 execute_s=sum(r.execute_s for r in prof),
-                rounds=sum(r.rounds for r in prof),
-                flops=_total(r.flops for r in prof),
-                bytes_accessed=_total(r.bytes_accessed for r in prof))
+                fetch_s=sum(r.fetch_s for r in prof),
+                rounds=sum(r.rounds for r in prof))
         return attrs
 
     def _finalize(self, work: _Work) -> None:
+        """A finished work: the plan-cost recheck, cache insert, tree
+        extraction and response of each of its tickets."""
+        with phase("finalize", dispatch=",".join(
+                str(r.seq) for r in work.profile)):
+            self._finalize_work(work)
+
+    def _finalize_work(self, work: _Work) -> None:
         srv = self.server
         if work.abandoned:
             # a zombie completed: the watchdog (or a winning hedge
@@ -1543,6 +1580,9 @@ class ServingRuntime:
         root.child("respond", latency_s=ticket.latency).close()
         self.tracer.finish(
             root, expected_spans=self._expected_spans(ticket, fast=fast))
+        if root is not NULL_SPAN:
+            # on this response alone: the plan cache never stores it
+            resp.timing_s = _timing(root)
         live = self._live_span(root)
         if missed:
             self.recorder.incident(

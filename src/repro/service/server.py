@@ -94,6 +94,10 @@ class PlanResponse:
     #              ``meta["shed"]`` / cost=inf fields stay for back-compat
     status: str = "exact"
     error: "Exception | None" = None
+    # seconds of the request's phases where the runtime traced it
+    # (admit, canonicalize, probe, queue_wait, and its dispatch's
+    # prepare, execute, fetch); on this response alone, never cached
+    timing_s: "dict | None" = None
 
 
 # --------------------------------------------------------------- telemetry

@@ -24,6 +24,8 @@ from repro.service import (PlanRequest, PlanServer, RuntimeConfig,
                            make_workload)
 
 DUR = {"admit": 0.0, "solve": 1.0, "single": 0.01}
+# admission's span with its phase children
+ADMIT = ("admit", (("canonicalize", ()), ("probe", ())))
 
 
 def _dur(kind, info):
@@ -155,7 +157,7 @@ def test_engine_dispatch_records_compile_execute_split():
     r = recs[0]
     assert not r.aot_cache_hit and r.compile_s > 0
     assert r.execute_s > 0 and r.rounds == fs.rounds
-    assert r.flops > 0 and r.bytes_accessed > 0
+    assert r.prepare_s > 0 and r.fetch_s > 0
     assert r.cost == "max" and r.n == 6 and r.B == 1
     # second solve: AOT cache hit, no compile time charged
     mark = engine_mod.dispatch_mark()
@@ -163,8 +165,9 @@ def test_engine_dispatch_records_compile_execute_split():
     r2 = engine_mod.dispatches_since(mark)[0]
     assert r2.aot_cache_hit and r2.compile_s == 0.0
     d = r.as_dict()
-    assert {"seq", "cost", "compile_s", "execute_s", "rounds",
-            "flops"} <= set(d)
+    assert {"seq", "cost", "compile_s", "prepare_s", "execute_s",
+            "fetch_s", "rounds"} <= set(d)
+    assert not {"flops", "bytes_accessed"} & set(d)
 
 
 # ----------------------------------------------------------- span trees
@@ -180,7 +183,7 @@ def test_deterministic_span_tree_batch_miss():
     rt.drain()
     assert t.done and not t.refused
     assert t.span.shape() == (
-        "request", (("admit", ()), ("queue_wait", ()), ("dispatch", ()),
+        "request", (ADMIT, ("queue_wait", ()), ("dispatch", ()),
                     ("extract", ()), ("respond", ())))
     d = t.span.find("dispatch")
     assert d.attrs["duration_s"] == 1.0          # injected solve time
@@ -216,7 +219,7 @@ def test_fast_path_span_tree_and_relabel_hit():
     t1 = rt.submit(req2)
     assert t1.done and t1.response.cache_hit
     assert t1.span.shape() == (
-        "request", (("admit", ()), ("fast_path", ()), ("respond", ())))
+        "request", (ADMIT, ("fast_path", ()), ("respond", ())))
     assert srv.cache.stats.relabel_hits >= 1
 
 
@@ -229,7 +232,7 @@ def test_coalesced_follower_span_tree():
     rt.drain()
     assert rt.stats.coalesced == 1
     assert t_follow.span.shape() == (
-        "request", (("admit", ()), ("coalesce", ()), ("queue_wait", ()),
+        "request", (ADMIT, ("coalesce", ()), ("queue_wait", ()),
                     ("dispatch", ()), ("extract", ()), ("respond", ())))
     assert t_follow.response.meta.get("coalesced") is True
     assert t_lead.span.find("coalesce") is None
@@ -249,7 +252,7 @@ def test_shed_span_tree_and_recorder_capture():
     miss = miss.__class__(**{**miss.__dict__, "slo": "strict"})
     t = rt.submit(miss)
     assert t.refused
-    assert t.span.shape() == ("request", (("admit", ()), ("shed", ())))
+    assert t.span.shape() == ("request", (ADMIT, ("shed", ())))
     rec = rt.recorder
     assert rec.counts["shed"] == 1
     assert rec.incidents[0]["kind"] == "shed"
