@@ -531,6 +531,41 @@ def bracket_update(lo, hi, piv, ok, active):
 
 
 # ------------------------------------------- on-device tree extraction
+LANE_BITS = 7                  # a TPU vector row: 2^7 = 128 lanes
+
+
+def xor_permute(x, s, n: int):
+    """``y[b, t] = x[b, t ^ s[b]]`` over the lattice axis of a (B, 2^n)
+    table, with no per-element index: n static flips, bit i's kept or
+    dropped per row by a ``where`` on bit i of ``s``.  A permutation, so
+    exact for any dtype.
+
+    The axis is viewed as (rows, lanes) with its low ``min(n, 7)`` bits
+    on the 128 lanes.  A row bit's flip reverses one axis of a static
+    reshape (whole lane rows trade places); a lane bit's swaps lanes
+    ``k = 2^i`` apart, two rolls and a select on the lane's own bit —
+    a lane-axis reshape would pad each piece out to 128 lanes.
+    """
+    B, size = x.shape
+    lb = min(n, LANE_BITS)
+    R, L = size >> lb, 1 << lb
+    v = x.reshape(B, R, L)
+
+    def flip_if(i, f, v):
+        return jnp.where(((s >> i) & 1).astype(bool)[:, None, None], f, v)
+
+    for j in range(n - lb):
+        f = v.reshape(B, R >> (j + 1), 2, 1 << j, L)[:, :, ::-1]
+        v = flip_if(lb + j, f.reshape(B, R, L), v)
+    lane = jnp.arange(L, dtype=jnp.int32)
+    for i in range(lb):
+        k = 1 << i
+        f = jnp.where((lane & k) == 0, jnp.roll(v, -k, axis=2),
+                      jnp.roll(v, k, axis=2))
+        v = flip_if(i, f, v)
+    return v.reshape(B, size)
+
+
 def extract_scan(dp, n: int, card=None):
     """Alg. 2 as a masked scan over tree slots — fully on device.
 
@@ -542,6 +577,13 @@ def extract_scan(dp, n: int, card=None):
     Total O(2^n n) per query — Alg. 2's bound, with the per-node submask
     *enumeration* replaced by a full-lattice masked reduction (the same
     uniformity trade the rest of the engine makes).
+
+    The complement half ``dp[S & ~T]`` is read as ``dp[T ^ S]`` through
+    ``xor_permute`` — static flips of the lattice axis, no per-element
+    gather.  Exact: on a submask T of S, ``S & ~T == S ^ T``, and every
+    T that is not a submask is masked to the worst error regardless of
+    what it read, so the errors that can win match the gather's bit for
+    bit, and so do the witnesses.
 
     Witness rule — matched to the host extractors for bit-identical
     trees: the *largest* T minimizing the witness error, because the
@@ -568,8 +610,7 @@ def extract_scan(dp, n: int, card=None):
         internal = pc[S] >= 2
         valid = (((T[None, :] & ~S[:, None]) == 0)
                  & (T[None, :] != 0) & (T[None, :] != S[:, None]))
-        comp = S[:, None] & ~T[None, :]
-        dpC = jnp.take_along_axis(dp, comp, axis=1)
+        dpC = xor_permute(dp, S, n)           # dp[S & ~T] where valid
         if card is None:
             err = 1 - ((dp > 0) & (dpC > 0)).astype(jnp.int32)
             worst = jnp.int32(2)

@@ -186,22 +186,152 @@ def test_minplus_value_layers_bitwise_vs_dpsub():
             assert np.array_equal(dev, ref)
 
 
-def test_extract_scan_matches_host_witness_rule():
-    n = 6
-    rng = np.random.default_rng(0)
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("n", [1, 4, 7, 10])
+def test_xor_permute_matches_index_xor(n, dtype):
+    """y[b, t] = x[b, t ^ s[b]] on both sides of the 128-lane split."""
+    rng = np.random.default_rng(n)
+    B = 3
+    x = rng.integers(-(1 << 30), 1 << 30, (B, 1 << n)).astype(dtype)
+    s = rng.integers(0, 1 << n, B).astype(np.int32)
+    s[0] = (1 << n) - 1
+    t = np.arange(1 << n)
+    want = x[np.arange(B)[:, None], t[None, :] ^ s[:, None]]
+    got = np.asarray(lattice.xor_permute(x, s, n))
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+def _extract_scan_gather(dp, n, card=None):
+    """The extraction scan as it read the complement table before the
+    XOR permutation: one (B, 2^n) gather ``dp[b, S & ~T]`` per slot.
+    The reference ``lattice.extract_scan`` is held to."""
+    import jax.numpy as jnp
+    from jax import lax
+    B, size = dp.shape
+    M = 2 * n - 1
+    pc = jnp.asarray(popcounts(n), dtype=jnp.int32)
+    T = jnp.arange(size, dtype=jnp.int32)
+    ar = jnp.arange(B)
+
+    def body(r, carry):
+        nodes, lidx, w = carry
+        S = nodes[:, r]
+        internal = pc[S] >= 2
+        valid = (((T[None, :] & ~S[:, None]) == 0)
+                 & (T[None, :] != 0) & (T[None, :] != S[:, None]))
+        comp = S[:, None] & ~T[None, :]
+        dpC = jnp.take_along_axis(dp, comp, axis=1)
+        if card is None:
+            err = 1 - ((dp > 0) & (dpC > 0)).astype(jnp.int32)
+            worst = jnp.int32(2)
+        else:
+            target = f64bits.add(
+                jnp.take_along_axis(dp, S[:, None], axis=1),
+                f64bits.neg(jnp.take_along_axis(card, S[:, None], axis=1)))
+            err = f64bits.abs_(f64bits.add(f64bits.add(dp, dpC),
+                                           f64bits.neg(target)))
+            worst = jnp.int64(f64bits.INF)
+        err = jnp.where(valid, err, worst)
+        twit = (size - 1 - jnp.argmin(err[:, ::-1], axis=1)) \
+            .astype(jnp.int32)
+        wc = jnp.minimum(w, M - 2)
+        left = jnp.where(internal, twit, nodes[ar, wc])
+        right = jnp.where(internal, S & ~twit, nodes[ar, wc + 1])
+        nodes = nodes.at[ar, wc].set(left)
+        nodes = nodes.at[ar, wc + 1].set(right)
+        lidx = lidx.at[:, r].set(jnp.where(internal, wc, 0))
+        w = w + 2 * internal.astype(jnp.int32)
+        return nodes, lidx, w
+
+    nodes0 = jnp.zeros((B, M), jnp.int32).at[:, 0].set(size - 1)
+    lidx0 = jnp.zeros((B, M), jnp.int32)
+    w0 = jnp.ones((B,), jnp.int32)
+    nodes, lidx, _ = lax.fori_loop(0, M, body, (nodes0, lidx0, w0))
+    return nodes, lidx
+
+
+def _extraction_tables(n, table):
+    """Two rows of random integer cardinalities (ties abound, so the
+    witness rule is exercised) with different optima, and each row's
+    table as the programs hand it to ``extract_scan``: the {0,1}
+    feasibility table at the C_max optimum (``table="max"``) or the
+    C_out value table as f64 bits (``table="out"``)."""
     from repro.core.layered import feasibility_dp_ref
+    rng = np.random.default_rng(n)
     pc = popcounts(n)
-    for seed in range(4):
-        card = rng.integers(1, 50, 1 << n).astype(np.float64)
-        gamma = dpconv_max_ref(card, n)
-        gate = np.where(pc >= 2, (card <= gamma).astype(float), 1.0)
-        dp = feasibility_dp_ref(gate, n)
-        nodes, lidx = lattice.extract_scan(np.asarray(dp)[None, :], n)
-        dev = jointree.tree_from_split_arrays(np.asarray(nodes)[0],
-                                              np.asarray(lidx)[0])
-        host = jointree.extract_tree_feasibility(dp, card, n)
+    cards = [rng.integers(1, 50, 1 << n).astype(np.float64)
+             for _ in range(2)]
+    if table == "max":
+        gammas = [dpconv_max_ref(card, n) for card in cards]
+        dps = [feasibility_dp_ref(
+            np.where(pc >= 2, (card <= g).astype(float), 1.0), n)
+            for card, g in zip(cards, gammas)]
+        assert gammas[0] != gammas[1]
+        return cards, dps, np.stack(dps).astype(np.int32), None
+    dps = [dpsub(card, n, mode="out") for card in cards]
+    assert dps[0][-1] != dps[1][-1]
+    return (cards, dps, f64bits.to_bits(np.stack(dps)),
+            f64bits.to_bits(np.stack(cards)))
+
+
+@pytest.mark.parametrize("table", ["max", "out"])
+@pytest.mark.parametrize("n", [6, 9, 12])
+def test_extract_scan_matches_host_witness_rule(n, table):
+    """The XOR-permuted complement read gives the gather reference's
+    split arrays, row for row, and the host extractors' trees."""
+    cards, dps, dp, card = _extraction_tables(n, table)
+    nodes, lidx = lattice.extract_scan(dp, n, card=card)
+    ref_nodes, ref_lidx = _extract_scan_gather(dp, n, card=card)
+    assert np.array_equal(np.asarray(nodes), np.asarray(ref_nodes))
+    assert np.array_equal(np.asarray(lidx), np.asarray(ref_lidx))
+    for b, (c, d) in enumerate(zip(cards, dps)):
+        dev = jointree.tree_from_split_arrays(np.asarray(nodes)[b],
+                                              np.asarray(lidx)[b])
+        if table == "max":
+            host = jointree.extract_tree_feasibility(d, c, n)
+            assert dev.cost_max(c) == dpconv_max_ref(c, n)
+        else:
+            host = jointree.extract_tree_out(d, c, n)
         assert repr(dev) == repr(host)
-        assert dev.validate() and dev.cost_max(card) == gamma
+        assert dev.validate()
+
+
+def _table_gathers(fn, *args) -> list:
+    """Element counts of the results of every gather in ``fn``'s lowered
+    module (the while body's included)."""
+    import re
+    import jax
+    text = jax.jit(fn).lower(*args).as_text()
+    sizes = []
+    for line in text.splitlines():
+        if "gather" not in line or "->" not in line:
+            continue
+        dims = re.search(r"->\s*tensor<([0-9x]*)x?[a-z]\w*>", line)
+        if dims is None:
+            continue
+        sizes.append(int(np.prod([int(d) for d in dims.group(1).split("x")
+                                  if d] or [1])))
+    return sizes
+
+
+@pytest.mark.parametrize("table", ["max", "out"])
+def test_extract_scan_reads_complement_without_table_gather(table):
+    """No gather in the lowered scan yields a (B, 2^n) tensor: the
+    complement half comes from the XOR permutation.  The slot reads'
+    (B,)-sized gathers stay; the gather reference shows what the check
+    catches."""
+    n, B = 12, 2
+    _, _, dp, card = _extraction_tables(n, table)
+    args = (dp,) if card is None else (dp, card)
+    scan = (lambda d: lattice.extract_scan(d, n)) if card is None else \
+        (lambda d, c: lattice.extract_scan(d, n, card=c))
+    ref = (lambda d: _extract_scan_gather(d, n)) if card is None else \
+        (lambda d, c: _extract_scan_gather(d, n, card=c))
+    table_size = B << n
+    assert table_size in _table_gathers(ref, *args)
+    sizes = _table_gathers(scan, *args)
+    assert sizes and table_size not in sizes
+    assert max(sizes) < table_size
 
 
 def test_feasibility_layers_forms_agree():
