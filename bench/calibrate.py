@@ -48,7 +48,7 @@ def main(argv=None) -> int:
             harness.serve_pool(srv, mix, seed)
         win = harness.run_window(srv, mix, gen.Stream(mix, seed),
                                  args.seconds)
-        sound = harness.compare(win.recs, mix.cost, mix.check_sample, seed)
+        sound = harness.window_checks(win, mix, seed)
         sample = harness.sample(win.recs, mix.check_sample, seed)
         ctrl = harness.compare(sample, mix.cost, len(sample), seed,
                                answer=harness.control_answer(mix.cost))

@@ -36,6 +36,10 @@ LIMITS = {
     "bad_trees": 0,       # answers that are not a join tree of the query
     "opt_gap": 1e-11,     # |cost - reference optimum| / optimum, sampled
     "tree_gap": 1e-11,    # |cost of the returned tree - cost| / cost
+    # share of the window's requests that failed: raised, not exact, or
+    # answered by the failure ladder's host or GOO rung, as where the
+    # program's own plan-cost recheck caught a wrong fused answer
+    "failed_share": 0.1,
 }
 GRACE_S = 60.0            # how long past the window an answer may come
 SETUP_WAIT_S = 120.0      # how long set-up waits for its warm-up answers
@@ -416,6 +420,16 @@ def compare(recs: list, cost: str, sample: int, seed: int,
                 "limit": LIMITS[k]} for k, v in values.items()}
 
 
+def window_checks(win: "Window", mix: gen.Mix, seed: int) -> dict:
+    """What a run holds its window to: ``compare`` over every request,
+    and the share of the requests that failed."""
+    checks = compare(win.recs, mix.cost, mix.check_sample, seed)
+    share = win.n_failed() / len(win.recs) if win.recs else 0.0
+    checks["failed_share"] = {"value": share,
+                              "limit": LIMITS["failed_share"]}
+    return checks
+
+
 def control_answer(cost: str, dtype=np.float32):
     """The control: the reference, run in the precision below the
     configuration's, answering in the program's place."""
@@ -535,9 +549,18 @@ def stall_report(win: "Window", incidents, rt_t0: float, records: list,
         f"{g:.4f} s at {at:.4f} s" for g, at in gaps)]
     slow = sorted(records, key=lambda r: -(r.execute_s + r.compile_s))[:k]
     lines.append(f"longest dispatches (of the last {len(records)}): "
-                 + ", ".join(f"n={r.n} B={r.B} {r.cost} execute "
+                 + ", ".join(f"n={r.n} B={r.B} {r.cost} shards={r.shards} "
+                             f"devices={mesh_size(r)} execute "
                              f"{r.execute_s:.4f} s compile {r.compile_s:.4f}"
                              f" s" for r in slow))
+    meshes: dict = {}
+    for r in records:
+        key = (r.shards, mesh_size(r))
+        meshes[key] = meshes.get(key, 0) + 1
+    lines.append(f"dispatches by mesh (of the last {len(records)}): " + (
+        ", ".join(
+            f"shards={s} over {d} devices: {c}"
+            for (s, d), c in sorted(meshes.items())) or "none"))
     def at(i):
         t = i["info"].get("at", i["at"])
         return None if t is None else t - rt_t0
@@ -548,6 +571,12 @@ def stall_report(win: "Window", incidents, rt_t0: float, records: list,
     if len(mine) > 40:
         lines.append(f"... {len(mine) - 40} more incidents")
     return lines
+
+
+def mesh_size(r) -> int:
+    """How many distinct devices a dispatch ran on (its record's
+    ``devices`` is a (platform, device ids) pair)."""
+    return len(set(r.devices[1])) if r.devices else 1
 
 
 def peaks_for(kind: str, path: str = os.path.join(BENCH, "peaks.json")
@@ -568,6 +597,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     c0 = counter.snap()
     mix = cell.mix
     srv = build_server(cell.config)
+    m0 = engine_mod.dispatch_mark()
     warm, sent = set_up(srv, mix, seed)
     c1 = counter.snap()
     rt = srv.async_runtime()
@@ -590,6 +620,11 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     setup_s = time.perf_counter() - t0
     log(f"setup: {setup_s:.3f} s; prewarm compiled {warm['compiled']} "
         f"executables in {warm['seconds']:.3f} s; {sent} warm-up requests")
+    # each bucket's first execution after its load falls in set-up
+    log("set-up dispatches, in order: " + ", ".join(
+        f"{r.cost} B={r.B} shards={r.shards} execute {r.execute_s:.4f} s"
+        for r in sorted(engine_mod.dispatches_since(m0),
+                        key=lambda r: r.seq)))
     before = layer_snapshot(srv)
     mark, rt_t0 = engine_mod.dispatch_mark(), rt.clock.now()
     with GcWatch() as gcw:
@@ -600,10 +635,14 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
     c2 = counter.snap()
     rt.close()
     dev = _device()
-    stats = dev.memory_stats() or {} if dev is not None else {}
+    mem = memory_peaks(cell.entry["chips"])
+    peak = max(a + b for a, b in mem)
     log(f"compiles: setup {counter.diff(c0, c1)}; window "
         f"{counter.diff(c1, c2)}; engine exec_cache_misses "
         f"{engine_mod.stats().exec_cache_misses}")
+    log(f"peak bytes (in use, reserved) per device: {mem}")
+    for b in engine_mod.compiled_buckets():
+        log(f"memory_analysis {b['module']}: {b['memory']}")
     ladder = {k: v for k, v in rt.fstats.as_dict().items() if v}
     log(f"failure ladder and watchdog: {ladder or 'all 0'}")
     for line in stall_report(win, rt.recorder.incidents, rt_t0,
@@ -615,7 +654,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         f"{late['p50']:.4f} ms p95 {late['p95']:.4f} ms max "
         f"{max((r.sent - r.due for r in win.recs), default=0.0) * 1e3:.4f}"
         f" ms; collector {gcw.summary()}")
-    checks = compare(win.recs, mix.cost, mix.check_sample, seed)
+    checks = window_checks(win, mix, seed)
     ctx = {"window": win, "setup_s": setup_s, "peaks": peaks,
            "layers": {k: (after[k][0] - before[k][0],
                           after[k][1] - before[k][1]) for k in after},
@@ -645,7 +684,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         "platform": dev.platform if dev is not None else "none",
         "kind": dev.device_kind if dev is not None else "none",
         "count": device_count(),
-        "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+        "memory_peak_bytes": peak}
     if trace:
         result["device"]["busy_s"] = red.busy_s
         result["device"]["window_s"] = red.window_s
@@ -671,6 +710,18 @@ def require_tpu(chips: int):
 def _device():
     import jax
     return jax.devices()[0]
+
+
+def memory_peaks(chips: int) -> list:
+    """(peak bytes in use, peak bytes reserved) of each of the cell's
+    ``chips`` devices, where the backend reports them (0 where it does
+    not).  A TPU holds an executable's temporaries in its reservation,
+    outside the bytes in use, so a chip's peak is the two together."""
+    import jax
+    return [(int(m.get("peak_bytes_in_use", 0)),
+             int(m.get("peak_bytes_reserved", 0)))
+            for m in (d.memory_stats() or {}
+                      for d in jax.devices()[:chips])]
 
 
 def device_count() -> int:

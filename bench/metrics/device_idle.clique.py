@@ -1,9 +1,7 @@
 """Device: percent of the traced window in which no operation ran on the
 device (1 - union of device-op intervals / window)."""
+from bench import readers
 
 
 def read(ctx):
-    red = ctx["trace"]
-    if red is None or not red.window_s:
-        return None
-    return red.idle_share * 100.0
+    return readers.idle_percent(ctx)

@@ -1,0 +1,12 @@
+"""Engine: device self time of the connected C_out program's ``search``
+phase (the (min,+) layer sweep on f64 bit patterns, its per-layer
+all-reduces included, ``jax.named_scope("search")`` in the program's
+``core/lattice.py``) per lattice-program launch per chip in the traced
+window, in ms.  How ops are matched to launches and scopes:
+``bench/readers.py``.  None where the program gives no HLO texts, the
+trace has no module line, or no op carries a phase scope."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.scope_ms(ctx, "search")

@@ -317,6 +317,30 @@ def test_relabel_is_the_same_query():
     assert len(r.edges) == len(q.edges)
 
 
+@pytest.mark.parametrize("seed", SEEDS)
+def test_mesh_mix_routes_to_the_fused_out_lane(seed):
+    """The four-chip cell's queries (n = 15, connected, simple, density
+    at most 0.5) take the batch lane's DPccp route under the ceiling a
+    4-wide solve mesh lifts to; every one is the source's star."""
+    from repro.core import engine
+    from repro.service.router import Router, RouterConfig
+    cell = harness.load_cell("acyclic_out.mesh4")
+    assert engine.sharded_ceiling(13, 4) == 15
+    router = Router(RouterConfig(
+        fused_out_max_n=engine.sharded_ceiling(13, 4)))
+    stream = gen.Stream(cell.mix, seed)
+    for _ in range(6):
+        q = stream.next()
+        assert q.n == 15 and len(q.edges) == 14
+        assert len(set(q.edges)) == 14 and all(u < v for u, v in q.edges)
+        pq = harness.program_query(q)
+        assert pq.is_connected(pq.full_mask) and not pq.hyperedges
+        assert 2 * len(q.edges) / (15 * 14) <= 0.5
+        route = router.route(pq, cell.mix.cost)
+        assert (route.lane, route.method) == ("batch", "dpccp"), route
+        assert q.edges == gen.star(15)
+
+
 # ----------------------------------------------------- window arithmetic
 def _resp(status="exact", engine="fused"):
     return types.SimpleNamespace(status=status, meta={"engine": engine})
@@ -362,9 +386,10 @@ def test_metric_readers():
            "layers": {"admit": (2, 0.004), "fast_path": (0, 0.0),
                       "queue_wait": (1, 0.003), "execute": (2, 0.08)}}
     read = {m: harness.load_reader(m) for m in (
-        "plans_per_s", "setup_s", "dispatch_ms.clique",
+        "plans_per_s", "plans_per_s.mesh4", "setup_s", "dispatch_ms.clique",
         "device_idle.clique", "zeta_roofline")}
     assert read["plans_per_s"](ctx) == pytest.approx(2 / 1.0)
+    assert read["plans_per_s.mesh4"](ctx) == read["plans_per_s"](ctx)
     assert read["setup_s"](ctx) == 42.5
     assert read["dispatch_ms.clique"](ctx) == pytest.approx(40.0)
     # nothing to read: no dispatch, no trace
@@ -379,6 +404,55 @@ def test_metric_readers():
 # --------------------------------------------------------- BENCHMARK.json
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def chip_problems(workloads: list, configs: dict) -> list:
+    """Why the cells' chip counts break the four-chip rule (an empty list
+    if they do not): a cell takes 1 chip or 4; at most half the cells,
+    rounded down, and one always, take 4; and a cell's configuration
+    splits its solve over as many chips as the cell takes
+    (``batch_policy.solve_shards``), so that no cell pays for four chips
+    and uses one."""
+    bad = [f"{w['name']}: {w['chips']} chips" for w in workloads
+           if w["chips"] not in (1, 4)]
+    four = [w["name"] for w in workloads if w["chips"] == 4]
+    if len(four) > max(1, len(workloads) // 2):
+        bad.append(f"{len(four)} of {len(workloads)} cells take 4 chips")
+    for w in workloads:
+        shards = configs[w["config"]].get("batch_policy", {}).get(
+            "solve_shards", 1)
+        if shards != w["chips"]:
+            bad.append(f"{w['name']}: {w['chips']} chips, solve_shards "
+                       f"{shards}")
+    return bad
+
+
+def _cell(name, chips, config="c1"):
+    return {"name": name, "chips": chips, "config": config}
+
+
+ONE = {"batch_policy": {}}
+FOUR = {"batch_policy": {"solve_shards": 4}}
+
+
+@pytest.mark.parametrize("workloads,configs,problems", [
+    ([_cell("a", 1)], {"c1": ONE}, 0),
+    # one four-chip cell is always allowed, alone or beside another
+    ([_cell("a", 4, "c4")], {"c4": FOUR}, 0),
+    ([_cell("a", 1), _cell("b", 4, "c4")], {"c1": ONE, "c4": FOUR}, 0),
+    # two four-chip cells need four cells in all
+    ([_cell("a", 1), _cell("b", 4, "c4"), _cell("c", 4, "c4")],
+     {"c1": ONE, "c4": FOUR}, 1),
+    ([_cell("a", 1), _cell("b", 1), _cell("c", 4, "c4"),
+      _cell("d", 4, "c4")], {"c1": ONE, "c4": FOUR}, 0),
+    # only 1 or 4 chips
+    ([_cell("a", 2, "c4")], {"c4": FOUR}, 2),
+    # a four-chip cell whose configuration solves on one chip, and a
+    # one-chip cell whose configuration asks for a mesh
+    ([_cell("a", 1), _cell("b", 4)], {"c1": ONE}, 1),
+    ([_cell("a", 1, "c4")], {"c4": FOUR}, 1)])
+def test_four_chip_rule(workloads, configs, problems):
+    assert len(chip_problems(workloads, configs)) == problems
 
 
 def test_benchmark_definitions():
@@ -396,8 +470,12 @@ def test_benchmark_definitions():
         assert m["moves"] in e2e
     assert e2e["setup_s"]["bound"] <= 0.25
     assert all(0.01 <= m["bound"] <= 0.25 for m in e2e.values())
+    files = {c["name"]: harness.load_json(os.path.join(harness.ROOT,
+                                                       c["file"]))
+             for c in configs.values()}
+    assert chip_problems(bm["workloads"], files) == []
     for w in bm["workloads"]:
-        assert NAME.match(w["name"]) and w["chips"] == 1
+        assert NAME.match(w["name"])
         cell = harness.load_cell(w["name"])
         assert cell.config["name"] == w["config"] in configs
         assert cell.config["chips"] == w["chips"]

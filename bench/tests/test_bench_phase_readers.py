@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import harness, readers
 from bench import trace_reduce as T
 from bench.tests import test_bench_rehearsal as rehearsal
 
@@ -75,8 +75,7 @@ def max_program():
     engine.fused_dpconv_max(card[None], 6)
     texts = engine.compiled_hlo_texts()
     module = "jit_max_n6_B1_C64_xla"
-    scopes = harness.load_reader("search_ms.clique").__globals__[
-        "instruction_scopes"](texts[module])
+    scopes = readers.instruction_scopes(texts[module])
     entry = texts[module].split("ENTRY", 1)[1].splitlines()[1:]
     names = {}
     for line in entry:
@@ -112,8 +111,7 @@ def test_device_phase_readers(metric, ns, max_program, monkeypatch):
     _module, names = max_program
     read = harness.load_reader(metric)
     ctx, launches = _device_ctx(names)
-    monkeypatch.setitem(read.__globals__, "module_launches",
-                        lambda ops: launches)
+    monkeypatch.setattr(readers, "module_launches", lambda ops: launches)
     # two launches lie wholly inside the window; the third ends past it
     assert read(ctx) == pytest.approx(2 * ns * 1e-6 / 2)
 
@@ -126,16 +124,14 @@ def test_device_phase_readers_without_their_data(metric, max_program,
     read = harness.load_reader(metric)
     ctx, launches = _device_ctx(names)
     # a trace with no "XLA Modules" line (every CPU trace)
-    monkeypatch.setitem(read.__globals__, "module_launches",
-                        lambda ops: {})
+    monkeypatch.setattr(readers, "module_launches", lambda ops: {})
     assert read(ctx) is None
     # launches of modules the program never compiled
-    monkeypatch.setitem(read.__globals__, "module_launches", lambda ops: {
+    monkeypatch.setattr(readers, "module_launches", lambda ops: {
         DEV: [(s, e, "jit_fn") for s, e, _m in launches[DEV]]})
     assert read(ctx) is None
     # no op carries a phase scope
-    monkeypatch.setitem(read.__globals__, "module_launches",
-                        lambda ops: launches)
+    monkeypatch.setattr(readers, "module_launches", lambda ops: launches)
     ctx["trace"].ops[:] = [o for o in ctx["trace"].ops
                            if o.name in names[None]]
     assert read(ctx) is None
@@ -149,8 +145,7 @@ def test_device_phase_readers_without_their_data(metric, max_program,
 def test_device_reader_finds_no_module_line_in_a_cpu_trace(tmp_path,
                                                           monkeypatch):
     """The recorded CPU trace, where the harness's trace would be."""
-    read = harness.load_reader("search_ms.clique")
-    launches = read.__globals__["module_launches"]
+    launches = readers.module_launches
     ops = [T.Event("/host:CPU", "python", "x", 0.0, 1.0)]
     monkeypatch.setattr(harness, "ROOT", str(tmp_path))
     assert launches(ops) == {}            # no trace at all
@@ -170,10 +165,11 @@ def test_idle_under_admission_spans(monkeypatch):
     ops = [_op("%a = s32[] add(x)", 0, 10), _op("%b = s32[] add(x)", 20, 10)]
     red = T.Reduced(window_s=40e-9, busy_s=20e-9, devices=1, op_s={},
                     gaps=[], ops=ops, lo_ns=0.0, hi_ns=40.0)
-    monkeypatch.setitem(read.__globals__, "host_spans",
-                        lambda lo: [(5.0, 25.0), (35.0, 50.0)])
+    monkeypatch.setattr(readers, "host_spans",
+                        lambda lo, span: [(5.0, 25.0), (35.0, 50.0)]
+                        if span == "plan.admit" else [])
     assert read(_ctx(trace=red)) == pytest.approx(15 / 40 * 100)
-    monkeypatch.setitem(read.__globals__, "host_spans", lambda lo: [])
+    monkeypatch.setattr(readers, "host_spans", lambda lo, span: [])
     assert read(_ctx(trace=red)) is None
     assert read(_ctx()) is None
 
@@ -269,3 +265,177 @@ def test_traced_run_reports_the_phase_counters(interpret, tmp_path):
         <= got <= OLD | NEW
     # a CPU trace has no module line; the trace is not under .bench_trace
     assert not got & set(DEVICE) and "idle_admit.clique" not in got
+
+
+# ------------------------------------------- the four-chip cell's readers
+MESH = ("dispatch_ms.mesh4", "prepare_ms.mesh4", "search_ms.mesh4",
+        "merge_ms.mesh4", "extract_ms.mesh4", "device_idle.mesh4",
+        "canon_ms.mesh4", "fetch_ms.mesh4", "idle_admit.mesh4",
+        "idle_close_bucket.mesh4")
+MESH_SPANS = {"idle_admit.mesh4": "plan.admit",
+              "idle_close_bucket.mesh4": "plan.close_bucket"}
+MESH_DEVICE = ("search_ms.mesh4", "merge_ms.mesh4", "extract_ms.mesh4")
+MESH_MODULE = "jit_out_n15_B2_C0_xla_s4"
+LAYOUT = "{0,1:T(8,128)S(1)}"
+# one layer's merge as the v5e's compiler prints it (the sharded C_out
+# program at n = 15, B = 2), an async pair, and instructions that name an
+# all-reduce without being one
+MESH_HLO = f"""HloModule {MESH_MODULE}
+
+%region_4.5 (a: s32[], b: s32[]) -> s32[] {{
+  %add.1 = s32[] add(s32[] %a, s32[] %b)
+}}
+
+ENTRY %main {{
+  %and_convert_fusion.10 = s32[2,4096]{LAYOUT} fusion(%p), kind=kLoop, calls=%fc.1, metadata={{op_name="jit(<lambda>)/shard_map/search/and"}}
+  %all-reduce.33 = (s32[2,4096]{LAYOUT}, s32[2,4096]{LAYOUT}) all-reduce(%and_convert_fusion.10, %shift-right-arithmetic_convert_fusion.10), channel_id=1, replica_groups={{{{0,1,2,3}}}}, use_global_device_ids=true, to_apply=%region_4.5, metadata={{op_name="jit(<lambda>)/shard_map/search/psum" stack_frame_id=196}}, backend_config={{"flag_configs":[],"barrier_config":{{"barrier_type":"CUSTOM"}}}}
+  %all-reduce-start.2 = (s32[2,128]{{1,0}}, /*index=1*/s32[2,128]{{1,0}}) all-reduce-start(%x, %y), channel_id=2, replica_groups={{{{0,1,2,3}}}}, to_apply=%region_4.5, metadata={{op_name="jit(<lambda>)/shard_map/search/psum"}}
+  %all-reduce-done.2 = (s32[2,128]{{1,0}}, s32[2,128]{{1,0}}) all-reduce-done(%all-reduce-start.2), metadata={{op_name="jit(<lambda>)/shard_map/search/psum"}}
+  %get-tuple-element.5 = s32[2,4096]{LAYOUT} get-tuple-element(%all-reduce.33), index=0, metadata={{op_name="jit(<lambda>)/shard_map/search/psum"}}
+  %select_fusion.3 = s64[2,32768]{{1,0}} fusion(%get-tuple-element.5), kind=kLoop, calls=%fc.2, metadata={{op_name="jit(<lambda>)/shard_map/search/select_n"}}
+  %reverse.179 = s32[2,32768]{{1,0:T(2,128)}} reverse(%r), dimensions={{1}}, metadata={{op_name="jit(<lambda>)/shard_map/extract/rev"}}
+  %copy.3 = s32[2]{{0}} copy(%c)
+}}
+"""
+# (instruction, offset, duration) of one launch; the all-reduce's
+# duration is 20 + 4 d on chip d (a chip waits for the slowest)
+LAUNCH = [("and_convert_fusion.10", 0, 40), ("all-reduce.33", 40, None),
+          ("all-reduce-start.2", 75, 3), ("all-reduce-done.2", 80, 9),
+          ("get-tuple-element.5", 90, 2), ("select_fusion.3", 95, 11),
+          ("reverse.179", 110, 50), ("copy.3", 165, 7)]
+
+
+def _mesh_trace(lattice=(100, 400, 900), other=(600,)):
+    """Four TPU planes, each with launches of the mesh program at
+    ``lattice`` (the last ends past the window [0, 1000)) and of a module
+    the program never compiled at ``other``, each running ``LAUNCH``."""
+    events = [T.Event("/host:CPU", "python3", "bench.traced", 0.0, 1000.0)]
+    launches = {}
+    for d in range(4):
+        plane = f"/device:TPU:{d}"
+        launches[plane] = sorted(
+            [(float(t), float(t + 200), MESH_MODULE) for t in lattice]
+            + [(float(t), float(t + 190), "jit_fn") for t in other])
+        for t0 in lattice + other:
+            for name, at, dur in LAUNCH:
+                events.append(T.Event(plane, "XLA Ops",
+                                      f"%{name} = s32[2]{{0}} op(x)",
+                                      float(t0 + at),
+                                      float(dur or 20 + 4 * d)))
+    return events, launches
+
+
+@pytest.fixture
+def mesh_trace(monkeypatch):
+    from repro.core import engine
+    events, launches = _mesh_trace()
+    monkeypatch.setattr(engine, "compiled_hlo_texts",
+                        lambda: {MESH_MODULE: MESH_HLO})
+    monkeypatch.setattr(readers, "module_launches", lambda ops: launches)
+    return events
+
+
+def test_all_reduces_are_found_by_opcode():
+    assert readers.all_reduces(MESH_HLO) == {
+        "all-reduce.33": "all-reduce", "all-reduce-start.2": "all-reduce",
+        "all-reduce-done.2": "all-reduce"}
+    scopes = readers.instruction_scopes(MESH_HLO)
+    assert scopes["all-reduce.33"] == scopes["select_fusion.3"] == "search"
+    assert scopes["reverse.179"] == "extract" and "copy.3" not in scopes
+
+
+@pytest.mark.parametrize("metric,ns", [
+    # the merges: the all-reduce (26 ns averaged over the chips) and the
+    # async pair; not the tuple read nor the fusion that follow it
+    ("merge_ms.mesh4", 26 + 3 + 9),
+    ("search_ms.mesh4", 40 + 26 + 3 + 9 + 2 + 11),
+    ("extract_ms.mesh4", 50)])
+def test_mesh_device_readers_on_four_chips(metric, ns, mesh_trace):
+    """Two lattice launches lie wholly inside the window on each of the
+    four chips; the third ends past it, and the other module's launch
+    (whose ops carry the same instruction names) is no lattice launch."""
+    red = T.reduce(mesh_trace, "tpu")
+    assert red.devices == 4
+    read = harness.load_reader(metric)
+    assert read(_ctx(trace=red)) == pytest.approx(ns * 1e-6)
+
+
+def test_mesh_idle_is_averaged_over_the_four_chips(mesh_trace):
+    red = T.reduce(mesh_trace, "tpu")
+    busy = [sum(min(e.end_ns, 1000.0) - e.start_ns for e in red.ops
+                if e.plane == f"/device:TPU:{d}") for d in range(4)]
+    want = (1 - sum(busy) / 4 / 1000.0) * 100
+    for metric in ("device_idle.mesh4", "device_idle.clique"):
+        assert harness.load_reader(metric)(_ctx(trace=red)) == \
+            pytest.approx(want)
+
+
+def test_merge_reader_without_collectives(mesh_trace, monkeypatch):
+    """A program that runs on one chip has no all-reduce: the merge
+    reader finds nothing to read while the phase readers still read."""
+    from repro.core import engine
+    one_chip = "\n".join(line for line in MESH_HLO.splitlines()
+                         if "all-reduce" not in line.split("=")[0])
+    monkeypatch.setattr(engine, "compiled_hlo_texts",
+                        lambda: {MESH_MODULE: one_chip})
+    red = T.reduce(mesh_trace, "tpu")
+    assert harness.load_reader("merge_ms.mesh4")(_ctx(trace=red)) is None
+    assert harness.load_reader("search_ms.mesh4")(_ctx(trace=red)) == \
+        pytest.approx((40 + 2 + 11) * 1e-6)
+
+
+@pytest.mark.parametrize("metric", MESH_DEVICE)
+def test_mesh_device_readers_without_their_data(metric, mesh_trace,
+                                                monkeypatch):
+    from repro.core import engine
+    read = harness.load_reader(metric)
+    red = T.reduce(mesh_trace, "tpu")
+    assert read(_ctx()) is None                        # no trace
+    monkeypatch.setattr(readers, "module_launches", lambda ops: {})
+    assert read(_ctx(trace=red)) is None               # no module line
+    events, launches = _mesh_trace()
+    monkeypatch.setattr(readers, "module_launches", lambda ops: launches)
+    monkeypatch.delattr(engine, "compiled_hlo_texts")
+    assert read(_ctx(trace=red)) is None               # no HLO texts
+
+
+def test_mesh_counter_readers():
+    ctx = _ctx([_answered({"prepare": v, "canonicalize": v / 10,
+                           "fetch": v / 4}) for v in (0.02, 0.03, 0.5)])
+    ctx["layers"] = {"execute": (4, 2.0)}
+    assert harness.load_reader("dispatch_ms.mesh4")(ctx) == \
+        pytest.approx(500.0)
+    assert harness.load_reader("prepare_ms.mesh4")(ctx) == \
+        pytest.approx(30.0)
+    assert harness.load_reader("canon_ms.mesh4")(ctx) == \
+        pytest.approx(3.0)
+    assert harness.load_reader("fetch_ms.mesh4")(ctx) == \
+        pytest.approx(7.5)
+    bare = _ctx([_answered(None)])
+    bare["layers"] = {"execute": (0, 0.0)}
+    for metric in MESH:
+        assert harness.load_reader(metric)(bare) is None
+
+
+@pytest.mark.parametrize("metric", sorted(MESH_SPANS))
+def test_mesh_idle_under_host_spans(metric, mesh_trace, monkeypatch):
+    """Each reader takes the chips' idle time under its own host span:
+    open over [0, 100) (no launch yet: idle on every chip) and [600, 700)
+    (the other module's launch, idle over [660 + 4 d, 675), [678, 680),
+    [689, 690) and [692, 695) on chip d), so 121 - 4 d ns on chip d, 11.5
+    % of the window on average.  The other reader's span lies where the
+    chips are busy."""
+    span = MESH_SPANS[metric]
+    other = (MESH_SPANS.keys() - {metric}).pop()
+    red = T.reduce(mesh_trace, "tpu")
+
+    def spans(lo, name):
+        if name == span:
+            return [(0.0, 100.0), (600.0, 700.0)]
+        return [(100.0, 140.0)] if name == MESH_SPANS[other] else []
+    monkeypatch.setattr(readers, "host_spans", spans)
+    read = harness.load_reader(metric)
+    assert read(_ctx(trace=red)) == pytest.approx(11.5)
+    monkeypatch.setattr(readers, "host_spans", lambda lo, name: [])
+    assert read(_ctx(trace=red)) is None               # no such span
+    assert read(_ctx()) is None                        # no trace

@@ -6,14 +6,15 @@ comparison exactly as ``bench/run.py`` drives them, with the Pallas
 kernels in interpret mode where the clique lane runs them.  Beside the
 cell's closed loop, the same configuration is rehearsed under the
 generator's other loops (an open loop at a rate, a template pool with
-relabelled repeats) and under C_out on stars, so that the harness's
-window and the reference are tried on every path a traffic file can
-ask for.  A clean run must pass; a run answered by the failure ladder's
-host rung must count those answers as failed; and runs whose timed path
-is broken underneath (answers dropped from a batch, answers altered
-where they are produced) or whose answers come from the control must
-come out not correct.  The command itself still refuses to measure
-without a TPU."""
+relabelled repeats) and under C_out on stars, and the four-chip cell's
+own traffic file runs on the one device a test has, so that the
+harness's window and the reference are tried on every path a traffic
+file can ask for.  A clean run must pass; a run answered by the failure
+ladder's host rung must count those answers as failed, and is not
+correct; and runs whose timed path is broken underneath (answers
+dropped from a batch, answers altered where they are produced) or whose
+answers come from the control must come out not correct.  The command
+itself still refuses to measure without a TPU."""
 import dataclasses
 import os
 import subprocess
@@ -40,7 +41,12 @@ VARIANTS = {
     # C_out on stars: the fused connected-C_out lane and its reference
     "out": {"cost": "out", "n_values": (6, 7), "topologies": ("star",),
             "regimes": ("warehouse", "selective"), "check_sample": 8},
+    # the four-chip cell's own traffic file (stars under C_out, the
+    # paper regime) at n = 6-7, on the one device a test has
+    "acyclic": {"n_values": (6, 7), "check_sample": 8},
 }
+# the cell a variant starts from, where it is not CELL
+BASE = {"acyclic": "acyclic_out.mesh4"}
 
 
 @pytest.fixture(autouse=True)
@@ -49,10 +55,11 @@ def interpret(monkeypatch):
 
 
 def small_cell(variant: str = "closed", **changes) -> harness.Cell:
-    c = harness.load_cell(CELL)
+    c = harness.load_cell(BASE.get(variant, CELL))
     config = dict(c.config)
-    config["batch_policy"] = {**config.get("batch_policy", {}),
-                              "backend": "pallas"}
+    policy = {k: v for k, v in config.get("batch_policy", {}).items()
+              if k != "solve_shards"}  # a mesh needs more devices
+    config["batch_policy"] = {**policy, "backend": "pallas"}
     mix = {**SMALL, **VARIANTS[variant], **changes}
     return dataclasses.replace(c, config=config,
                                mix=dataclasses.replace(c.mix, **mix))
@@ -79,7 +86,9 @@ def window(cell: harness.Cell, seconds: float = 1.0, warm: bool = True,
 @pytest.mark.parametrize("variant,trace", [("closed", False),
                                            ("closed", True),
                                            ("open", False),
-                                           ("templates", True)])
+                                           ("templates", True),
+                                           ("acyclic", False),
+                                           ("acyclic", True)])
 def test_clean_run_passes(variant, trace, tmp_path):
     cell = small_cell(variant)
     lines = []
@@ -137,7 +146,7 @@ def _left_deep(solve):
 
 
 @pytest.mark.parametrize("fault", [_drop_half, _left_deep])
-@pytest.mark.parametrize("variant", ["closed", "out"])
+@pytest.mark.parametrize("variant", ["closed", "out", "acyclic"])
 def test_broken_timed_path_is_not_correct(variant, fault, monkeypatch):
     from repro.service.batch import BatchedSolver
     # four clients keep batches of several requests forming
@@ -150,7 +159,7 @@ def test_broken_timed_path_is_not_correct(variant, fault, monkeypatch):
     assert not harness.correct(checks), checks
 
 
-@pytest.mark.parametrize("variant", ["closed", "out"])
+@pytest.mark.parametrize("variant", ["closed", "out", "acyclic"])
 def test_control_is_not_correct(variant):
     """The control, the reference computed in float32 (the precision
     below the configuration's float64), answering in the program's
@@ -207,3 +216,7 @@ def test_host_failover_counts_as_failed():
     checks = harness.compare(host, cell.mix.cost, cell.mix.check_sample,
                              SEED)
     assert harness.correct(checks)
+    # a window answered by the ladder alone is not a correct run
+    checks = harness.window_checks(win, cell.mix, SEED)
+    assert checks["failed_share"]["value"] == 1.0
+    assert not harness.correct(checks)
