@@ -9,6 +9,15 @@ metric needs is found by name:
     bench/configs/<config>.json     server settings and the deployment
     bench/traffic/<traffic>.json    generator parameters and the loop
     bench/metrics/<metric>.py       ``read(ctx)`` -> number or None
+    bench/costs/<cost>.py           the cost function's plain reference
+
+A cost's reference gives four functions of a ``gen.Query``:
+``solve(q, dtype)`` -> (optimum, DP table in ``dtype``, the numbers an
+answer reports beside its value, which a sampled answer must match
+exactly); ``extract(q, table)`` -> a tree that realises the table;
+``tree_problems(tree, q, meta)`` -> why ``tree``, answered with ``meta``,
+is not a plan of the query under the cost (empty if it is); and
+``tree_cost(tree, q)``.
 """
 from __future__ import annotations
 
@@ -23,11 +32,11 @@ import time
 
 import numpy as np
 
-from bench import reference
 from bench.traffic import gen
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+COSTS = os.path.join(BENCH, "costs")
 
 # the limits every run's answers are held to; PERF.md gives the readings
 # of sound runs and of the control that each was set between
@@ -71,6 +80,8 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     config = load_json(os.path.join(root, cfg["file"]))
     mix = gen.Mix.from_dict(load_json(
         os.path.join(root, "bench", "traffic", w["traffic"] + ".json")))
+    for cost in {config["cost"], mix.cost}:
+        load_cost(cost)          # a cost without its reference ends here
 
     def mine(metrics):
         return [m for m in metrics
@@ -79,13 +90,26 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                 mine(bm["per_layer"]))
 
 
-def load_reader(metric: str):
-    path = os.path.join(BENCH, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str):
+    return _load(os.path.join(BENCH, "metrics", metric + ".py"),
+                 "bench_metric_" + metric.replace(".", "_")).read
+
+
+def load_cost(cost: str):
+    """The reference of one cost function, ``<COSTS>/<cost>.py``; stops
+    the run, naming the file, where there is none."""
+    path = os.path.join(COSTS, cost + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"no reference for cost {cost!r}: {path} is "
+                         "missing")
+    return _load(path, "bench_cost_" + cost)
 
 
 # ------------------------------------------------------------------ server
@@ -379,15 +403,19 @@ def answer_tree(tree):
 
 def compare(recs: list, cost: str, sample: int, seed: int,
             answer=None) -> dict:
-    """Hold the window's answers to the reference.  Every answer: it
-    came, it is a join tree of its query, and that tree costs what the
-    answer says.  A sample drawn from the seed: the cost is the
-    reference's optimum.  ``answer(rec) -> (cost, tree)`` replaces the
-    program's answers (the control puts the reference in their place).
-    Returns each number compared with its limit."""
+    """Hold the window's answers to the reference of ``cost``.  Every
+    answer: it came, it is a plan of its query under the cost, and that
+    tree costs what the answer says.  A sample drawn from the seed: the
+    cost is the reference's optimum, and each number the reference
+    reports beside it is the answer's, exactly.  ``answer(rec) -> (cost,
+    tree, meta)`` replaces the program's answers (the control puts the
+    reference in their place).  Returns each number compared with its
+    limit."""
+    ref = load_cost(cost)
     if answer is None:
         def answer(r):
-            return float(r.resp.cost), answer_tree(r.resp.tree)
+            return (float(r.resp.cost), answer_tree(r.resp.tree),
+                    r.resp.meta)
     lost = bad = 0
     tree_gap = 0.0
     answered = []
@@ -396,22 +424,22 @@ def compare(recs: list, cost: str, sample: int, seed: int,
             lost += 1
             continue
         q = r.query
-        value, tree = answer(r)
-        if tree is None or reference.tree_problems(tree, q.n, q.edges,
-                                                   cost):
+        value, tree, meta = answer(r)
+        if tree is None or ref.tree_problems(tree, q, meta):
             bad += 1
             continue
-        tc = reference.tree_cost(tree, q.card, cost)
-        tree_gap = max(tree_gap, _gap(tc, value))
-        answered.append((r, value))
+        tree_gap = max(tree_gap, _gap(ref.tree_cost(tree, q), value))
+        answered.append((r, value, meta))
     opt_gap = 0.0
     rng = np.random.default_rng([int(seed), 4])
     pick = rng.permutation(len(answered))[:sample]
     for i in sorted(pick):
-        r, value = answered[i]
-        q = r.query
-        opt, _dp = reference.solve(q.n, q.edges, q.card, cost)
-        opt_gap = max(opt_gap, _gap(value, float(opt)))
+        r, value, meta = answered[i]
+        opt, _dp, want = ref.solve(r.query)
+        gap = _gap(value, float(opt))
+        if any(meta.get(k) != v for k, v in want.items()):
+            gap = math.inf     # a reported number off by any amount
+        opt_gap = max(opt_gap, gap)
     values = {"lost": lost, "bad_trees": bad, "opt_gap": opt_gap,
               "tree_gap": tree_gap}
     # a gap that is not a finite number fails its limit, and is printed
@@ -433,10 +461,11 @@ def window_checks(win: "Window", mix: gen.Mix, seed: int) -> dict:
 def control_answer(cost: str, dtype=np.float32):
     """The control: the reference, run in the precision below the
     configuration's, answering in the program's place."""
+    ref = load_cost(cost)
+
     def answer(r):
-        q = r.query
-        opt, dp = reference.solve(q.n, q.edges, q.card, cost, dtype)
-        return float(opt), reference.extract(q.n, q.edges, q.card, cost, dp)
+        opt, dp, meta = ref.solve(r.query, dtype)
+        return float(opt), ref.extract(r.query, dp), meta
     return answer
 
 

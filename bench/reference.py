@@ -1,19 +1,18 @@
-"""The plain reference the benchmark holds every answer to.
+"""The lattice the benchmark's plain references are built from.
 
 Written from the cost models' definitions and sharing nothing with the
-program: the optimal C_max over the full subset lattice (bushy trees,
-cross products allowed, as DPsub computes it) and the optimal C_out over
-connected subgraphs joined along an edge (no cross products, as DPccp
-computes it), both as O(3^n) dynamic programs over submask splits,
-vectorized one popcount layer at a time.
+program.  Each cost function's reference, ``bench/costs/<cost>.py``,
+states its dynamic program over submask splits with these pieces: the
+split pairs of every set, one popcount layer at a time; the relations
+adjacent to a set and which sets are connected; the layered sweep
 
-    C_max:  DP[S] = max(c(S), min_{A+B=S} max(DP[A], DP[B]))
-    C_out:  DP[S] = min_{A+B=S, A~B} (DP[A] + DP[B]) + c(S)
     DP[{i}] = 0
+    DP[S] = close(min_{A+B=S, keep(A, B)} pair(DP[A], DP[B]), c(S))
 
-``dtype`` is the precision the DP runs in: float64 is the configuration's
-own; float32 is the control, the step below it, which the comparison
-must refuse.
+in the precision it is given (float64 is the configurations' own;
+float32 is the control, the step below it, which the comparison must
+refuse); the extraction of a tree whose every split realises its DP
+value; and the walk that checks a tree covers every relation once.
 """
 from __future__ import annotations
 
@@ -89,45 +88,37 @@ def connected(n: int, edges: tuple) -> np.ndarray:
     return out
 
 
-def solve(n: int, edges, card: np.ndarray, cost: str,
-          dtype=np.float64) -> tuple:
-    """The optimal value and the DP table of one query."""
+def layered(n: int, card: np.ndarray, dtype, pair, close,
+            keep=None) -> np.ndarray:
+    """The DP table of ``DP[S] = close(min over the splits A+B=S that
+    ``keep(A, B)`` holds of pair(DP[A], DP[B]), c(S))``, singletons 0, a
+    set without a kept split +inf; vectorized one popcount layer at a
+    time, in ``dtype``."""
     s_all, a_all, starts = split_pairs(n)
     c = np.asarray(card, dtype)
     dp = np.full(1 << n, np.inf, dtype)
     dp[1 << np.arange(n)] = 0
-    if cost == "out":
-        conn = connected(n, edges)
-        nbr = adjacency(n, edges)
     for k in range(2, n + 1):
         lo, hi = starts[k], starts[k + 1]
         s, a = s_all[lo:hi], a_all[lo:hi]
         b = s ^ a
-        if cost == "max":
-            vals = np.maximum(dp[a], dp[b])
-        elif cost == "out":
-            ok = conn[a] & conn[b] & ((nbr[a] & b) != 0)
+        if keep is not None:
+            ok = keep(a, b)
             s, a, b = s[ok], a[ok], b[ok]
-            vals = dp[a] + dp[b]
-        else:
-            raise ValueError(f"unknown cost {cost!r}")
         if len(s) == 0:
             continue
         heads = np.flatnonzero(np.r_[True, s[1:] != s[:-1]])
-        best = np.minimum.reduceat(vals, heads)
+        best = np.minimum.reduceat(pair(dp[a], dp[b]), heads)
         sets = s[heads]
-        dp[sets] = (np.maximum(best, c[sets]) if cost == "max"
-                    else best + c[sets])
-    return dp[-1], dp
+        dp[sets] = close(best, c[sets])
+    return dp
 
 
-def extract(n: int, edges, card: np.ndarray, cost: str, dp: np.ndarray
-            ) -> tuple:
-    """An optimal tree from a DP table, as nested (left, right) tuples of
-    relation masks (a leaf is its mask)."""
-    c = np.asarray(card, dp.dtype)
-    conn = connected(n, edges) if cost == "out" else None
-    nbr = adjacency(n, edges) if cost == "out" else None
+def extract(n: int, realises) -> tuple:
+    """A tree over all n relations whose every join splits its set S
+    into A and B with ``realises(S, A, B)``, as nested (left, right)
+    tuples of relation masks (a leaf is its mask).  Splits are tried in
+    submask order, the first that realises wins."""
 
     def build(s: int):
         if s & (s - 1) == 0:
@@ -138,13 +129,7 @@ def extract(n: int, edges, card: np.ndarray, cost: str, dp: np.ndarray
         while True:
             if sub != rest:
                 a, bb = low | sub, rest ^ sub
-                if cost == "max":
-                    v = max(dp[a], dp[bb])
-                    ok = max(v, c[s]) == dp[s]
-                else:
-                    ok = (conn[a] and conn[bb] and (nbr[a] & bb) != 0
-                          and dp[a] + dp[bb] + c[s] == dp[s])
-                if ok:
+                if realises(s, a, bb):
                     return (build(a), build(bb))
             if sub == 0:
                 break
@@ -154,41 +139,36 @@ def extract(n: int, edges, card: np.ndarray, cost: str, dp: np.ndarray
     return build((1 << n) - 1)
 
 
-def tree_cost(tree, card: np.ndarray, cost: str) -> float:
-    """C_max or C_out of a (left, right) tuple tree."""
-    vals = []
+def intermediates(tree) -> list:
+    """The relation set of every join of a (left, right) tuple tree, in
+    post-order."""
+    sets = []
 
     def walk(t):
         if isinstance(t, tuple):
             m = walk(t[0]) | walk(t[1])
-            vals.append(float(card[m]))
+            sets.append(m)
             return m
         return int(t)
 
     walk(tree)
-    if cost == "max":
-        return max(vals)
-    total = 0.0
-    for v in vals:
-        total += v
-    return total
+    return sets
 
 
-def tree_problems(tree, n: int, edges, cost: str) -> list:
+def tree_problems(tree, n: int, join=None) -> list:
     """Why ``tree`` is not a join tree over all n relations (an empty
-    list if it is).  C_out trees join connected inputs along an edge."""
+    list if it is).  ``join(a, b)``, where given, names what is wrong
+    with joining inputs a and b, or returns None."""
     bad = []
-    conn = connected(n, edges) if cost == "out" else None
-    nbr = adjacency(n, edges) if cost == "out" else None
 
     def walk(t) -> int:
         if isinstance(t, tuple):
             a, b = walk(t[0]), walk(t[1])
             if a & b:
                 bad.append(f"inputs {a:#x} and {b:#x} overlap")
-            if cost == "out" and not (conn[a] and conn[b]
-                                      and nbr[a] & b):
-                bad.append(f"join of {a:#x} and {b:#x} is a cross product")
+            problem = join(a, b) if join is not None else None
+            if problem:
+                bad.append(problem)
             return a | b
         t = int(t)
         if t <= 0 or t & (t - 1):

@@ -7,9 +7,9 @@ Builds the ``PlanServer`` its configuration describes, prewarms exactly
 the buckets the cell's traffic hits, sends warm-up traffic, then drives
 ``PlanServer.plan_async`` for ``--seconds`` (open loop at a fixed rate,
 or closed loop with a fixed number of clients) and holds every answer to
-the plain reference in ``bench/reference.py``.  ``--trace 1`` is a
-separate run that also profiles a few seconds of the window and reports
-the per-layer metrics instead of the end-to-end ones.
+the plain reference of the traffic's cost, ``bench/costs/<cost>.py``.
+``--trace 1`` is a separate run that also profiles a few seconds of the
+window and reports the per-layer metrics instead of the end-to-end ones.
 
 The last line of standard output is the result object; counts, the
 generator's lateness and the compared numbers with their limits come

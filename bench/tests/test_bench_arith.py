@@ -488,3 +488,6 @@ def test_benchmark_definitions():
     for c in configs.values():
         cfg = harness.load_json(os.path.join(harness.ROOT, c["file"]))
         assert cfg["reduced"] == c["reduced"]
+        # every configuration's cost has its reference
+        assert os.path.isfile(os.path.join(harness.COSTS,
+                                           cfg["cost"] + ".py"))

@@ -6,15 +6,16 @@ comparison exactly as ``bench/run.py`` drives them, with the Pallas
 kernels in interpret mode where the clique lane runs them.  Beside the
 cell's closed loop, the same configuration is rehearsed under the
 generator's other loops (an open loop at a rate, a template pool with
-relabelled repeats) and under C_out on stars, and the four-chip cell's
-own traffic file runs on the one device a test has, so that the
-harness's window and the reference are tried on every path a traffic
-file can ask for.  A clean run must pass; a run answered by the failure
-ladder's host rung must count those answers as failed, and is not
-correct; and runs whose timed path is broken underneath (answers
-dropped from a batch, answers altered where they are produced) or whose
-answers come from the control must come out not correct.  The command
-itself still refuses to measure without a TPU."""
+relabelled repeats), under C_out on stars and under C_cap on cliques,
+and the four-chip cell's own traffic file runs on the one device a test
+has, so that the harness's window and each cost's reference are tried
+on every path a traffic file can ask for.  A clean run must pass; a run
+answered by the failure ladder's host rung must count those answers as
+failed, and is not correct; and runs whose timed path is broken
+underneath (answers dropped from a batch, answers altered where they are
+produced, C_cap's gate left out) or whose answers come from the control
+must come out not correct.  The command itself still refuses to measure
+without a TPU."""
 import dataclasses
 import os
 import subprocess
@@ -44,6 +45,8 @@ VARIANTS = {
     # the four-chip cell's own traffic file (stars under C_out, the
     # paper regime) at n = 6-7, on the one device a test has
     "acyclic": {"n_values": (6, 7), "check_sample": 8},
+    # C_cap on the cell's cliques: the fused two-pass cap program
+    "cap": {"cost": "cap", "n_values": (7, 8, 9), "check_sample": 8},
 }
 # the cell a variant starts from, where it is not CELL
 BASE = {"acyclic": "acyclic_out.mesh4"}
@@ -88,7 +91,8 @@ def window(cell: harness.Cell, seconds: float = 1.0, warm: bool = True,
                                            ("open", False),
                                            ("templates", True),
                                            ("acyclic", False),
-                                           ("acyclic", True)])
+                                           ("acyclic", True),
+                                           ("cap", False)])
 def test_clean_run_passes(variant, trace, tmp_path):
     cell = small_cell(variant)
     lines = []
@@ -146,7 +150,7 @@ def _left_deep(solve):
 
 
 @pytest.mark.parametrize("fault", [_drop_half, _left_deep])
-@pytest.mark.parametrize("variant", ["closed", "out", "acyclic"])
+@pytest.mark.parametrize("variant", ["closed", "out", "acyclic", "cap"])
 def test_broken_timed_path_is_not_correct(variant, fault, monkeypatch):
     from repro.service.batch import BatchedSolver
     # four clients keep batches of several requests forming
@@ -159,7 +163,41 @@ def test_broken_timed_path_is_not_correct(variant, fault, monkeypatch):
     assert not harness.correct(checks), checks
 
 
-@pytest.mark.parametrize("variant", ["closed", "out", "acyclic"])
+def _gate_left_out(solve):
+    """Each answer is replaced where it is produced by the unpruned C_out
+    plan, with that plan's own cost: the cap's gate left out of the
+    second pass."""
+    from repro.core import baselines, jointree
+
+    def broken(self, items, **kw):
+        res = solve(self, items, **kw)
+        for r, item in zip(res, items):
+            q, card = item[0], item[1]
+            dp = baselines.dpsub(card, q.n, mode="out")
+            r.tree = jointree.extract_tree_out(dp, card, q.n)
+            r.cost = r.tree.cost_out(card)
+        return res
+    return broken
+
+
+def test_cap_gate_left_out_is_not_correct(monkeypatch):
+    from repro.service.batch import BatchedSolver
+    cell = small_cell("cap", loop="closed", clients=4)
+    # the window is long enough to reach queries whose unpruned optimum
+    # breaks the cap: about one in fifty of the cell's cliques at n = 7-9
+    win = window(cell, seconds=3.0, broken=lambda: monkeypatch.setattr(
+        BatchedSolver, "solve", _gate_left_out(BatchedSolver.solve)))
+    cap, out = harness.load_cost("cap"), harness.load_cost("out")
+    # on a clique every set is connected, so C_out is unpruned there
+    assert any(out.solve(r.query)[0] < cap.solve(r.query)[0]
+               for r in win.recs)
+    checks = harness.compare(win.recs, cell.mix.cost, cell.mix.check_sample,
+                             SEED)
+    assert not harness.correct(checks), checks
+    assert checks["bad_trees"]["value"] > 0
+
+
+@pytest.mark.parametrize("variant", ["closed", "out", "acyclic", "cap"])
 def test_control_is_not_correct(variant):
     """The control, the reference computed in float32 (the precision
     below the configuration's float64), answering in the program's

@@ -142,7 +142,7 @@ def make_query(rng: np.random.Generator, n: int, topology: str,
 class Mix:
     """A traffic mix, as its ``bench/traffic/<name>.json`` file gives it."""
     loop: str                        # "open" (rate) | "closed" (clients)
-    cost: str                        # "max" | "out"
+    cost: str                        # its reference: bench/costs/<cost>.py
     n_values: tuple
     topologies: tuple
     regimes: tuple
