@@ -292,6 +292,41 @@ def subset_signature(q: QueryGraph, card: np.ndarray, mask: int,
                       rels=rels, perm=perm)
 
 
+def subset_fingerprint(q: QueryGraph, card: np.ndarray, mask: int) -> str:
+    """A cheap relabeling-invariant digest of the sub-problem ``mask``
+    induces, for rejecting value-fragment probes before paying
+    ``subset_signature`` (which builds and permutes a ``2^r`` table).
+
+    It hashes ``r``, the number of induced hyperedges, the bits of
+    ``card[mask]``, the sorted bits of the member singletons'
+    cardinalities and the sorted induced degree sequence: O(n + edges)
+    host work, no ``2^r`` array.
+
+    Soundness: equal ``subset_signature`` keys mean byte-equal canonical
+    bytes, i.e. two induced sub-problems that are relabelings of one
+    another with byte-equal cardinality tables — so their fingerprints
+    are equal.  Unequal fingerprints therefore prove unequal keys; equal
+    fingerprints prove nothing (the caller computes the signature).
+    Cardinalities are compared as float64 bit patterns, as the key does.
+    """
+    mask = int(mask)
+    rels = [i for i in range(q.n) if (mask >> i) & 1]
+    deg = dict.fromkeys(rels, 0)
+    for u, v in q.edges:
+        if (mask >> u) & 1 and (mask >> v) & 1:
+            deg[u] += 1
+            deg[v] += 1
+    hyper = sum(1 for a, b in q.hyperedges if (a | b) & mask == (a | b))
+    bits = np.asarray(card, np.float64)[
+        [mask] + [1 << i for i in rels]].view(np.uint64)
+    byt = b"".join((
+        np.array([len(rels), hyper], np.uint64).tobytes(),
+        bits[:1].tobytes(),
+        np.sort(bits[1:]).tobytes(),
+        np.sort(np.fromiter(deg.values(), np.uint64, len(rels))).tobytes()))
+    return hashlib.blake2b(byt, digest_size=16).hexdigest()
+
+
 def relabel_tree(tree: "JoinTree | None", perm) -> "JoinTree | None":
     """Map a join tree's relation labels through ``perm`` (bit i -> perm[i]).
 

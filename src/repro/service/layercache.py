@@ -28,7 +28,11 @@ two granularities:
 
 Fragments are stored in *fragment-canonical* label space and mapped
 through each query's subset permutation on insert and probe, so
-relabeled sub-structures hit.  Seeds are always a pure performance hint:
+relabeled sub-structures hit.  Each stored fragment also carries a cheap
+relabeling-invariant fingerprint (``canon.subset_fingerprint``); a probe
+whose fingerprint no stored fragment shares is a miss without paying the
+subset's canonical signature — fresh cardinalities, which can never hit,
+cost O(n) per subset instead of a ``2^r`` table permutation.  Seeds are always a pure performance hint:
 every consumer produces bit-identical tables, optima and trees with or
 without them (the seeded values equal what the lattice would compute —
 asserted by the parity property tests and the serve_bench reuse row).
@@ -46,12 +50,14 @@ import zipfile
 
 import numpy as np
 
-from repro.service.canon import subset_expand, subset_signature
+from repro.service.canon import (subset_expand, subset_fingerprint,
+                                 subset_signature)
 
 # on-disk fragment-store format version (``save``/``load``): bump on any
 # layout change — ``load`` ignores files whose version doesn't match
-# (a stale store is a cold start, never a crash or a wrong seed)
-STORE_VERSION = 1
+# (a stale store is a cold start, never a crash or a wrong seed).
+# 2: value fragments carry their ``subset_fingerprint``.
+STORE_VERSION = 2
 
 
 @dataclasses.dataclass
@@ -61,6 +67,7 @@ class LayerCacheStats:
     search_inserts: int = 0
     value_hits: int = 0         # fragment probes that found a sub-table
     value_misses: int = 0       # fragment probes that found nothing
+    value_prefilter_misses: int = 0  # of those, answered by fingerprint
     value_inserts: int = 0
     seeded_solves: int = 0      # solves dispatched with >= 1 seed attached
     seeded_sets: int = 0        # lattice sets covered by value seeds
@@ -84,6 +91,7 @@ class LayerCacheStats:
                 "search_hit_rate": round(self.search_hit_rate, 4),
                 "value_hits": self.value_hits,
                 "value_misses": self.value_misses,
+                "value_prefilter_misses": self.value_prefilter_misses,
                 "value_inserts": self.value_inserts,
                 "value_hit_rate": round(self.value_hit_rate, 4),
                 "seeded_solves": self.seeded_solves,
@@ -143,6 +151,13 @@ class LayerCache:
             collections.OrderedDict()
         self._values: "collections.OrderedDict[str, np.ndarray]" = \
             collections.OrderedDict()
+        # fingerprint index over ``_values``: every stored key's
+        # ``subset_fingerprint`` and a count per fingerprint.  A probe
+        # whose fingerprint has no count cannot match any stored key,
+        # so it skips ``subset_signature``.  Kept in step with
+        # ``_values`` on every insert, eviction and clear.
+        self._value_fp: dict = {}
+        self._fp_count: collections.Counter = collections.Counter()
         # probe memo: a value probe pays n+1 subset canonicalizations,
         # and replay streams repeat canonical forms heavily — memoize
         # (form.key, lane) -> (generation, payload, stat deltas) and
@@ -237,6 +252,11 @@ class LayerCache:
                 # a larger hit fragment already covered this mask's
                 # whole power set
                 continue
+            if not self._fp_count[subset_fingerprint(form.q, form.card,
+                                                     mask)]:
+                self.stats.value_misses += 1
+                self.stats.value_prefilter_misses += 1
+                continue
             sf = subset_signature(form.q, form.card, mask)
             frag = self._values.get(sf.key)
             if frag is None:
@@ -306,16 +326,37 @@ class LayerCache:
             frag = np.empty(1 << sf.r, np.float64)
             # fragment-canonical labels: frag[sigma[t]] = dp[expand[t]]
             frag[sigma] = dp[expand]
-            self._values[sf.key] = frag
+            self._put_value(sf.key, frag,
+                            subset_fingerprint(form.q, form.card, mask))
             self.stats.value_inserts += 1
             self._gen += 1
             while len(self._values) > self.value_capacity:
-                self._values.popitem(last=False)
+                self._pop_value()
                 self.stats.evictions += 1
                 self._gen += 1
         if len(self._observed) > 8192:
             self._observed.clear()
         self._observed[form.key] = self._gen
+
+    def _put_value(self, key: str, frag: np.ndarray, fp: str) -> None:
+        """Store (or refresh) a value fragment as most recent, with its
+        fingerprint in the index."""
+        if key in self._value_fp:
+            self._uncount(self._value_fp[key])
+        self._values[key] = frag
+        self._values.move_to_end(key)
+        self._value_fp[key] = fp
+        self._fp_count[fp] += 1
+
+    def _pop_value(self) -> None:
+        """Evict the least recently used value fragment."""
+        key, _ = self._values.popitem(last=False)
+        self._uncount(self._value_fp.pop(key))
+
+    def _uncount(self, fp: str) -> None:
+        self._fp_count[fp] -= 1
+        if not self._fp_count[fp]:
+            del self._fp_count[fp]
 
     def _insert_search(self, key: str, opt: float) -> None:
         if key in self._search:
@@ -332,6 +373,8 @@ class LayerCache:
     def clear(self) -> None:
         self._search.clear()
         self._values.clear()
+        self._value_fp.clear()
+        self._fp_count.clear()
         self._probe_memo.clear()
         self._observed.clear()
         self._gen += 1
@@ -341,14 +384,17 @@ class LayerCache:
         """Write both stores to ``path`` (npz, ``STORE_VERSION``-stamped).
 
         Keys are hex sha256 strings — stored as fixed-width unicode
-        arrays; value fragments are concatenated f64 with an offsets
-        array (they have heterogeneous ``2^r`` lengths).  The write is
+        arrays, each value key beside its fingerprint; value fragments
+        are concatenated f64 with an offsets array (they have
+        heterogeneous ``2^r`` lengths).  The write is
         atomic (tmp + ``os.replace``) so a crashed replica never leaves
         a truncated store for the next prewarm to trip on.  Returns the
         number of entries written."""
         skeys = np.array(list(self._search.keys()), dtype="U64")
         svals = np.array(list(self._search.values()), np.float64)
         vkeys = np.array(list(self._values.keys()), dtype="U64")
+        vfps = np.array([self._value_fp[k] for k in self._values],
+                        dtype="U32")
         frags = list(self._values.values())
         offsets = np.zeros(len(frags) + 1, np.int64)
         for i, f in enumerate(frags):
@@ -360,7 +406,7 @@ class LayerCache:
             np.savez_compressed(
                 fh, version=np.int64(STORE_VERSION),
                 search_keys=skeys, search_vals=svals,
-                value_keys=vkeys, value_data=vdata,
+                value_keys=vkeys, value_fps=vfps, value_data=vdata,
                 value_offsets=offsets)
         os.replace(tmp, path)
         return len(skeys) + len(vkeys)
@@ -379,13 +425,15 @@ class LayerCache:
                 skeys = [str(k) for k in z["search_keys"]]
                 svals = np.asarray(z["search_vals"], np.float64)
                 vkeys = [str(k) for k in z["value_keys"]]
+                vfps = [str(f) for f in z["value_fps"]]
                 vdata = np.asarray(z["value_data"], np.float64)
                 offsets = np.asarray(z["value_offsets"], np.int64)
         except (OSError, KeyError, ValueError, zipfile.BadZipFile):
             # a truncated write surfaces as BadZipFile, not OSError
             return 0
         if len(skeys) != svals.shape[0] \
-                or offsets.shape[0] != len(vkeys) + 1:
+                or offsets.shape[0] != len(vkeys) + 1 \
+                or len(vfps) != len(vkeys):
             return 0
         loaded = 0
         for k, v in zip(skeys, svals):
@@ -399,9 +447,8 @@ class LayerCache:
             frag = vdata[offsets[i]:offsets[i + 1]].copy()
             if k not in self._values:
                 loaded += 1
-            self._values[k] = frag
-            self._values.move_to_end(k)
+            self._put_value(k, frag, vfps[i])
         while len(self._values) > self.value_capacity:
-            self._values.popitem(last=False)
+            self._pop_value()
         self._gen += 1                  # invalidate probe/observe memos
         return loaded

@@ -18,6 +18,7 @@ the cache-correctness bugfix sweep that rides with it:
 * the quarantine TTL boundary (the audit): refused on ``[t0, t0+ttl)``,
   admitted at exactly ``t0 + ttl``.
 """
+import collections
 import dataclasses
 
 import numpy as np
@@ -27,14 +28,17 @@ from hypothesis import strategies as st
 
 from repro.core import engine as engine_mod
 from repro.core.dpconv import optimize
-from repro.core.querygraph import (chain, clique, make_cardinalities,
-                                   permute_card, relabel, star)
+from repro.core.querygraph import (QueryGraph, chain, clique, cycle,
+                                   make_cardinalities, permute_card,
+                                   relabel, star)
 from repro.service import (PlanRequest, PlanServer, RuntimeConfig,
                            VirtualClock, WorkloadSpec, make_workload)
 from repro.service import faults
 from repro.service import layercache as layercache_mod
 from repro.service.batch import BatchPolicy
-from repro.service.canon import canonicalize
+from repro.service.canon import (canonicalize, induced_subproblem,
+                                 subset_expand, subset_fingerprint,
+                                 subset_signature)
 from repro.service.layercache import LayerCache
 
 TOPOLOGIES = {"chain": chain, "star": star, "clique": clique}
@@ -382,6 +386,16 @@ def test_load_is_best_effort_on_missing_version_and_corruption(tmp_path):
     vpath = str(tmp_path / "stale.npz")
     np.savez_compressed(vpath, **stale)
     assert LayerCache().load(vpath) == 0
+    # a version-1 store (no value fingerprints) is a cold start, and so
+    # is a current stamp on an archive that lacks them
+    v1 = {k: v for k, v in stale.items() if k != "value_fps"}
+    v1["version"] = np.int64(1)
+    v1path = str(tmp_path / "v1.npz")
+    np.savez_compressed(v1path, **v1)
+    assert LayerCache().load(v1path) == 0
+    v1["version"] = np.int64(layercache_mod.STORE_VERSION)
+    np.savez_compressed(v1path, **v1)
+    assert LayerCache().load(v1path) == 0
     # truncated/garbage file
     cpath = tmp_path / "corrupt.npz"
     cpath.write_bytes(open(path, "rb").read()[:40])
@@ -474,3 +488,227 @@ def test_admission_gate_keeps_paying_topologies_and_can_be_disabled():
         off.observe(ff, "max", sol.cost, sol.meta)
     assert off.stats.search_inserts == 6
     assert off.stats.admission_skips == 0
+
+
+# ------------------------------------------------- fingerprint prefilter
+FP_TOPOLOGIES = {"chain": chain, "star": star, "cycle": cycle,
+                 "clique": clique}
+
+
+def _probe_masks(n: int) -> list:
+    full = (1 << n) - 1
+    return [full] + [full ^ (1 << i) for i in range(n)]
+
+
+def _pure_dp(card) -> np.ndarray:
+    """A stand-in C_out table that, like the real one, is a pure
+    function of each subset's induced sub-problem (here of ``c(S)``
+    alone), so fragments transfer consistently between queries."""
+    return np.log1p(np.asarray(card, np.float64)) * 3.0 + 0.125
+
+
+def _reference_probe(lc, form):
+    """The value probe as it was before the fingerprint check: every
+    subset's canonical signature computed, looked up in ``_values``.
+    Reads the store without touching it; returns (payload, hits,
+    misses)."""
+    n = form.q.n
+    vals = np.zeros(1 << n, np.float64)
+    ok = np.zeros(1 << n, bool)
+    hits = misses = 0
+    for mask in _probe_masks(n):
+        if ok[mask]:
+            continue
+        sf = subset_signature(form.q, form.card, mask)
+        frag = lc._values.get(sf.key)
+        if frag is None:
+            misses += 1
+            continue
+        hits += 1
+        expand = subset_expand(sf.rels)
+        vals[expand] = frag[layercache_mod._perm_masks(sf.perm)]
+        ok[expand] = True
+    if not hits:
+        return None, hits, misses
+    ok[layercache_mod._popcounts(n) < 2] = False
+    return {"vals": vals, "ok": ok}, hits, misses
+
+
+def _assert_index_consistent(lc):
+    assert set(lc._value_fp) == set(lc._values)
+    assert lc._fp_count == collections.Counter(lc._value_fp.values())
+    assert all(c > 0 for c in lc._fp_count.values())
+
+
+def _assert_probe_matches_reference(lc, form):
+    """Soundness at every probed subset, then ``seed_for`` against the
+    reference probe: payload bit for bit, same hit and miss counts."""
+    for mask in _probe_masks(form.q.n):
+        if subset_signature(form.q, form.card, mask).key in lc._values:
+            fp = subset_fingerprint(form.q, form.card, mask)
+            assert lc._fp_count[fp] > 0, "the fingerprint rejected a match"
+    ref, hits, misses = _reference_probe(lc, form)
+    h0, m0 = lc.stats.value_hits, lc.stats.value_misses
+    lc._probe_memo.clear()              # probe afresh, not from the memo
+    got = lc.seed_for(form, "out")
+    assert lc.stats.value_hits - h0 == hits
+    assert lc.stats.value_misses - m0 == misses
+    if ref is None:
+        assert got is None
+        return 0
+    assert got is not None
+    assert got["ok"].tobytes() == ref["ok"].tobytes()
+    assert got["vals"].tobytes() == ref["vals"].tobytes()
+    return hits
+
+
+def _relabeled_leave_one_out(q, card, i, perm_seed):
+    """The sub-problem of ``(q, card)`` without relation ``i``, as a
+    query of its own under a random relabeling."""
+    q_sub, card_sub, _ = induced_subproblem(q, card,
+                                            q.full_mask ^ (1 << i))
+    perm = np.random.default_rng(perm_seed).permutation(q.n - 1)
+    return canonicalize(relabel(q_sub, perm),
+                        permute_card(card_sub, q.n - 1, perm))
+
+
+@pytest.mark.parametrize("top", sorted(FP_TOPOLOGIES))
+@settings(max_examples=6, deadline=None)
+@given(n=st.integers(min_value=5, max_value=9),
+       card_seed=st.integers(min_value=0, max_value=10_000),
+       perm_seed=st.integers(min_value=0, max_value=10_000),
+       capacity=st.integers(min_value=3, max_value=24))
+def test_fingerprint_prefilter_never_rejects_a_match(tmp_path_factory, top,
+                                                     n, card_seed,
+                                                     perm_seed, capacity):
+    """Soundness of the fingerprint check: through inserts, LRU
+    evictions at a small capacity, ``clear`` and a save/load round
+    trip, every probed subset whose signature is stored passes the
+    check, and ``seed_for`` returns the reference probe's payload bit
+    for bit with the same hit and miss counts."""
+    q = FP_TOPOLOGIES[top](n)
+    card = make_cardinalities(q, seed=card_seed)
+    form = canonicalize(q, card)
+    lc = LayerCache(value_capacity=capacity, admission_min_probes=0)
+    lc.observe(form, "out", 0.0, {}, dp=_pure_dp(form.card))
+    # a distractor of the same shape: fresh cardinalities, more evictions
+    other = canonicalize(q, make_cardinalities(q, seed=card_seed + 1))
+    lc.observe(other, "out", 0.0, {}, dp=_pure_dp(other.card))
+    lc.observe(form, "out", 0.0, {}, dp=_pure_dp(form.card))
+    _assert_index_consistent(lc)
+
+    perm = np.random.default_rng(perm_seed).permutation(n)
+    whole = canonicalize(relabel(q, perm), permute_card(card, n, perm))
+    probes = [whole] + [_relabeled_leave_one_out(q, card, i, perm_seed + i)
+                        for i in range(n)]
+    hits = sum(_assert_probe_matches_reference(lc, f) for f in probes)
+    assert hits >= 1                   # the whole query's set is stored
+    _assert_index_consistent(lc)
+
+    path = str(tmp_path_factory.mktemp("fp") / "layers.npz")
+    lc.save(path)
+    back = LayerCache(value_capacity=capacity, admission_min_probes=0)
+    assert back.load(path) == len(lc._values)
+    _assert_index_consistent(back)
+    assert back._value_fp == lc._value_fp
+    for f in probes:
+        _assert_probe_matches_reference(back, f)
+
+    lc.clear()
+    assert not lc._value_fp and not lc._fp_count
+    for f in probes:
+        assert _assert_probe_matches_reference(lc, f) == 0
+
+
+def test_fingerprint_is_relabeling_invariant_with_hyperedges():
+    """Equal signature keys give equal fingerprints on a hypergraph,
+    whose hyperedge count is part of the fingerprint."""
+    q = QueryGraph(6, ((0, 1), (1, 2), (2, 3), (3, 4), (4, 5)),
+                   ((0b000011, 0b100000), (0b000100, 0b011000)))
+    card = np.random.default_rng(3).uniform(1.0, 1e6, 1 << 6)
+    perm = (3, 0, 5, 1, 4, 2)
+    q2, card2 = relabel(q, perm), permute_card(card, 6, perm)
+    for mask in range(1, 1 << 6):
+        mask2 = sum(1 << perm[i] for i in range(6) if (mask >> i) & 1)
+        a = subset_signature(q, card, mask)
+        b = subset_signature(q2, card2, mask2)
+        assert a.key == b.key
+        assert (subset_fingerprint(q, card, mask)
+                == subset_fingerprint(q2, card2, mask2))
+    # a hyperedge fully inside the subset is counted; with the same
+    # edges and cardinalities, dropping it changes the fingerprint
+    plain = QueryGraph(6, q.edges, q.hyperedges[1:])
+    assert (subset_fingerprint(q, card, 0b111111)
+            != subset_fingerprint(plain, card, 0b111111))
+
+
+def test_fresh_star_probes_skip_the_signature(monkeypatch):
+    """Fresh cardinalities can never hit: against a store filled from
+    other fresh star queries, ``seed_for`` computes no subset signature,
+    answers all n+1 subsets by fingerprint, and the ``layercache``
+    provider reports the count.  After evictions the fingerprint index
+    equals the one recomputed from the keys that remain."""
+    n = 10
+    srv = _server(enable_cache=False)
+    lc = srv.layers
+    lc.value_capacity = 30
+    lc.admission_min_probes = 0
+    expected_fp = {}
+    for s in range(4):
+        q = star(n)
+        form = canonicalize(q, make_cardinalities(q, seed=700 + s))
+        for mask in _probe_masks(n):
+            expected_fp[subset_signature(form.q, form.card, mask).key] = \
+                subset_fingerprint(form.q, form.card, mask)
+        lc.observe(form, "out", 0.0, {}, dp=_pure_dp(form.card))
+    assert lc.stats.evictions == 4 * (n + 1) - 30
+    assert len(lc._values) == 30
+    assert lc._fp_count == collections.Counter(
+        expected_fp[k] for k in lc._values)
+    _assert_index_consistent(lc)
+
+    calls = []
+    real = layercache_mod.subset_signature
+
+    def counting(*args, **kw):
+        calls.append(args[2])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(layercache_mod, "subset_signature", counting)
+    for s in range(3):
+        q = star(n)
+        form = canonicalize(q, make_cardinalities(q, seed=800 + s))
+        before = lc.stats.value_prefilter_misses
+        misses = lc.stats.value_misses
+        assert lc.seed_for(form, "out") is None
+        assert lc.stats.value_prefilter_misses - before == n + 1
+        assert lc.stats.value_misses - misses == n + 1
+    assert calls == []
+    prov = srv.registry.snapshot()["providers"]["layercache"]
+    assert prov["value_prefilter_misses"] == 3 * (n + 1)
+    assert prov["value_misses"] == lc.stats.value_misses
+
+
+def test_relabeled_value_hit_survives_save_load(tmp_path):
+    """A version-2 store round trip keeps the fingerprints, so a
+    relabeled sub-structure still hits after ``load``."""
+    big = chain(7)
+    card_big = make_cardinalities(big, seed=3)
+    form_big = canonicalize(big, card_big)
+    lc = LayerCache()
+    lc.observe(form_big, "out", 0.0, {}, dp=_pure_dp(form_big.card))
+    path = str(tmp_path / "layers.npz")
+    lc.save(path)
+    with np.load(path) as z:
+        assert int(z["version"]) == layercache_mod.STORE_VERSION == 2
+        assert len(z["value_fps"]) == len(z["value_keys"]) == 8
+
+    back = LayerCache()
+    assert back.load(path) == 8
+    form2 = _relabeled_leave_one_out(big, card_big, 6, 7)
+    seed = back.seed_for(form2, "out")
+    assert seed is not None and back.stats.value_hits == 1
+    assert back.stats.value_prefilter_misses == 0   # covered by the hit
+    ok = seed["ok"]
+    assert ok[(1 << 6) - 1]
+    assert np.array_equal(seed["vals"][ok], _pure_dp(form2.card)[ok])
