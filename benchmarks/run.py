@@ -3,8 +3,7 @@
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only NAME]
 
 Prints ``name,us_per_call,derived`` CSV lines per benchmark plus the
-detailed per-figure tables.  Dry-run roofline rows are read from
-benchmarks/results/dryrun (generated by ``repro.launch.dryrun --all``).
+detailed per-figure tables.
 """
 from __future__ import annotations
 
@@ -15,7 +14,6 @@ import sys
 import time
 
 from benchmarks import figures
-from benchmarks.roofline_report import load_results
 from repro.core import engine
 
 RESULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -109,23 +107,6 @@ def bench_planner(quick: bool):
     return rows
 
 
-def bench_roofline(quick: bool):
-    d = os.path.join(RESULTS, "dryrun")
-    if not os.path.isdir(d):
-        print("roofline: no dry-run results — run repro.launch.dryrun "
-              "--all first", file=sys.stderr)
-        return []
-    rows = load_results(d, "pod")
-    for r in rows:
-        if r["status"] != "ok":
-            continue
-        _csv(f"roofline/{r['arch']}/{r['shape']}",
-             max(r["t_compute"], r["t_memory"], r["t_collective"]),
-             f"bottleneck={r['bottleneck']};"
-             f"mfu_bound={r.get('mfu_bound', 0):.3f}")
-    return rows
-
-
 BENCHES = {
     "fig6": bench_fig6,
     "fig5": bench_fig5,
@@ -135,7 +116,6 @@ BENCHES = {
     "greedy_gap": bench_greedy_gap,
     "kernels": bench_kernels,
     "planner": bench_planner,
-    "roofline": bench_roofline,
 }
 
 

@@ -27,7 +27,7 @@ single-device builds (or the same width on different devices) can never
 alias one cache slot; ``prewarm`` compiles the buckets a configured
 server can hit before traffic arrives (killing the cold-bucket p99
 spike), and ``stats()`` exposes dispatch/solve/round counters that
-``benchmarks/serve_bench.py`` asserts on.
+the serving tests assert on (one dispatch per fused solve).
 
 Exactness: identical to the host paths — all feasibility values are
 exact integer counts (int64 up to n = 26 on the XLA backend, int32 up to
@@ -37,8 +37,8 @@ int64 bits and the (min,+) pass reproduces DPsub[out]'s f64 operations
 bit for bit (``f64bits``: a TPU's own f64 keeps ~48 significand bits),
 and the extraction scan applies the host extractors'
 witness rule, so optima, C_out values and join trees are bit-identical
-(tests/test_engine.py, tests/test_lattice_parity.py, and the
-serve_bench parity sweep).
+(tests/test_engine.py, tests/test_lattice_parity.py,
+tests/test_golden_plans.py).
 """
 from __future__ import annotations
 
@@ -300,7 +300,7 @@ def solve_mesh(shards: int):
     engine owns the lookup)."""
     m = _SOLVE_MESHES.get(shards)
     if m is None:
-        from repro.launch.mesh import make_solve_mesh
+        from repro.core.mesh import make_solve_mesh
         m = _SOLVE_MESHES[shards] = make_solve_mesh(shards)
     return m
 
@@ -311,7 +311,7 @@ def _mesh_identity(shards: int) -> tuple:
     single-device executables — or the same width on *different*
     devices — must never alias.  Single-device solves are keyed by the
     default device's identity for the same reason."""
-    from repro.launch.mesh import mesh_fingerprint
+    from repro.core.mesh import mesh_fingerprint
     if shards > 1:
         return mesh_fingerprint(solve_mesh(shards))
     d = jax.devices()[0]
@@ -523,7 +523,8 @@ def candidate_bucket(n: int) -> int:
     serving tier a *closed* executable space keyed by (n, B_bucket)
     alone: ``prewarm`` can compile every bucket a configured server will
     ever hit, so no arrival pattern can run into a cold candidate
-    bucket (the p99 spike serve_bench's cold-latency row measures).
+    bucket (``tests/test_lattice_parity.py``: no cold bucket survives
+    prewarm).
     """
     return _next_pow2(max((1 << n) - n - 1, 1))
 

@@ -651,7 +651,7 @@ def _solve_axis(shards: int, mesh) -> "str | None":
         return None
     if mesh is None:
         raise ValueError(f"shards={shards} needs a solve mesh")
-    from repro.launch.mesh import SOLVE_AXIS
+    from repro.core.mesh import SOLVE_AXIS
     (axis,) = mesh.axis_names
     if axis != SOLVE_AXIS or mesh.devices.size != shards:
         raise ValueError(
@@ -797,7 +797,7 @@ def build_max_program(n: int, direct_layers: int, backend: str,
     scan all run on device; the only host transfer is the result tuple.
 
     ``shards > 1`` runs the program under ``shard_map`` over ``mesh``
-    (a ``launch.mesh.make_solve_mesh`` 1-D mesh of ``shards`` devices):
+    (a ``core.mesh.make_solve_mesh`` 1-D mesh of ``shards`` devices):
     the direct-layer sweeps partition their sets axis per device with
     one collective combine per layer.  Inputs/outputs stay replicated —
     same shapes, bit-identical results.

@@ -1,16 +1,10 @@
-"""Production mesh builders.
-
-Single pod: (16, 16) = 256 chips, axes (data, model).
-Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model) — the 'pod'
-axis crosses the DCN boundary; FSDP spans (pod, data), TP stays inside a
-pod on 'model' (ICI).
+"""The 1-D solve mesh for sharded lattice solves.
 
 Functions, not module constants: importing this module never touches jax
-device state (the dry-run sets XLA_FLAGS before any jax import).
+device state.
 
-Every mesh has ``Auto`` axes: the model code places arrays with
-``with_sharding_constraint`` and the solve programs with ``shard_map``,
-both written for the partitioner to propagate shardings (``jax.make_mesh``
+The mesh axis is ``Auto``: the solve programs run under ``shard_map``,
+written for the partitioner to propagate shardings (``jax.make_mesh``
 defaults to ``Explicit`` axes).
 """
 from __future__ import annotations
@@ -21,20 +15,6 @@ from jax.sharding import AxisType
 
 def _auto_mesh(shape: tuple, axes: tuple):
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
-
-
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host) devices exist — used by tests
-    and the CPU train example."""
-    n = len(jax.devices())
-    assert data * model <= n, (data, model, n)
-    return _auto_mesh((data, model), ("data", "model"))
 
 
 SOLVE_AXIS = "solve"

@@ -20,9 +20,8 @@ objects; this package gives them one story:
 * ``recorder`` — the flight recorder: a bounded ring buffer of
   completed span trees plus an always-on capture of every shed /
   downgraded / deadline-missed request, dumpable as JSON lines.
-* ``export``   — renders a registry as a JSON snapshot (merged into
-  serve_bench's ``BENCH_serve.json`` rows) and as Prometheus text
-  format for the future distributed front end.
+* ``export``   — renders a registry as a JSON snapshot and as
+  Prometheus text format for the future distributed front end.
 
 Wiring: ``PlanServer`` owns a ``MetricsRegistry``; ``ServingRuntime``
 owns a ``Tracer`` + ``FlightRecorder`` bound to that registry and its
@@ -30,10 +29,9 @@ clock; ``repro.core.engine`` emits per-dispatch profiling records
 (AOT-cache hit/miss, compile / prepare / execute / fetch split,
 while-loop rounds, bucket key) that the runtime attributes to the spans
 that waited on each dispatch, and every traced response carries its
-phases' seconds in ``PlanResponse.timing_s``.  ``scripts/smoke.sh`` gates
-on the resulting telemetry (zero unclosed spans, per-lane span shapes,
-exact shed/missed capture, tracing overhead) via serve_bench's ``obs``
-row.
+phases' seconds in ``PlanResponse.timing_s``.  ``tests/test_obs.py``
+holds the resulting telemetry to its contract (zero unclosed spans,
+per-lane span shapes, exact shed/downgraded/missed capture).
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry, default_registry)

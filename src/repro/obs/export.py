@@ -1,7 +1,7 @@
 """Registry export: JSON snapshots and Prometheus text format.
 
-``registry_snapshot`` is what serve_bench merges into its
-``BENCH_serve.json`` rows; ``prometheus`` renders the same registry in
+``registry_snapshot`` renders a registry as one JSON-able dict;
+``prometheus`` renders the same registry in
 the text exposition format (``# TYPE`` headers, cumulative
 ``_bucket{le=...}`` series for histograms) so a future distributed
 front end can be scraped without new code.

@@ -22,7 +22,7 @@ Timestamps come EXCLUSIVELY from the runtime's ``Clock`` abstraction —
 on a ``VirtualClock`` span trees are bit-deterministic and tests assert
 their exact ``shape()``.  On close, each span's duration feeds a
 ``trace.<name>_s`` histogram in the bound ``MetricsRegistry``, giving
-the per-phase p50/p95 breakdown that serve_bench's ``obs`` row reports.
+the per-phase p50/p95 breakdown (``export.span_phase_summary``).
 
 Disabled tracing costs one attribute check per call site: ``Tracer``
 hands out the shared ``NULL_SPAN``, whose every method is a no-op.

@@ -47,10 +47,9 @@ class ContractionLog:
 
     ``plan_contraction(..., logger=log)`` records every contraction it
     plans; a saved log replays through the serving tier
-    (``repro.service.workload.make_einsum_workload`` +
-    ``benchmarks/serve_bench.py --workload einsum``), so the plan server
-    is exercised by the contraction mix a real run actually issued
-    instead of synthetic query templates only.
+    (``repro.service.workload.make_einsum_workload``), so the plan
+    server is exercised by the contraction mix a real run actually
+    issued instead of synthetic query templates only.
     """
 
     def __init__(self, records: "list | None" = None):
@@ -75,10 +74,10 @@ class ContractionLog:
 
 
 def builtin_trace() -> "list[Contraction]":
-    """A canned contraction trace shaped like the repo's model stack.
+    """A canned contraction trace shaped like a model stack's steps.
 
     Each entry is a multi-operand tensor network mirroring an einsum
-    chain the model layer actually runs (fused attention with Q/K/V
+    chain a model layer runs (fused attention with Q/K/V
     projections, gated MLP, MoE routing, SSM state scan, LoRA update,
     cross-attention), with dims from the small-config family.  Used as
     the default replay workload when no logged trace is supplied —
@@ -124,24 +123,51 @@ def builtin_trace() -> "list[Contraction]":
     ]
 
 
-def model_planner_trace(cfg=None, batch: int = 4, seq: int = 64,
-                        layers: "int | None" = None,
-                        logger: "ContractionLog | None" = None
-                        ) -> "list[Contraction]":
-    """Contractions the model stack's train/serve steps actually plan.
+@dataclasses.dataclass(frozen=True)
+class TraceShape:
+    """The widths and layer pattern of one model architecture that
+    ``model_planner_trace`` derives its contractions from.
+
+    ``family`` is one of dense, moe, ssm, hybrid, encdec or vlm;
+    ``n_heads = 0`` means attention-free and ``head_dim = 0`` means
+    ``d_model // n_heads``.
+    """
+    name: str
+    family: str
+    n_layers: int
+    d_model: int
+    n_heads: int
+    d_ff: int
+    vocab_size: int
+    head_dim: int = 0
+    n_experts: int = 0
+    ssm_state: int = 0
+    n_enc_layers: int = 0
+    n_frames: int = 1500            # encoder output length
+    # hybrid: one shared attention+MLP block applied every k ssm layers
+    hybrid_attn_every: int = 0
+
+    def layer_is_attn(self, i: int) -> bool:
+        """hybrid: which backbone positions get the shared attention block
+        applied after them."""
+        if self.family != "hybrid" or self.hybrid_attn_every == 0:
+            return False
+        return (i + 1) % self.hybrid_attn_every == 0
+
+
+def model_planner_trace(cfg: TraceShape, batch: int = 4,
+                        seq: int = 64) -> "list[Contraction]":
+    """Contractions a model's train and serve steps plan.
 
     Where ``builtin_trace`` is a canned sampler of *shapes* of model
-    traffic, this derives the einsum structures straight from the
-    ``repro.train.steps`` step builders for a concrete ``ModelConfig``:
-    per layer the fused-attention core (Q/K/V projections + QK^T + AV),
-    the same chain extended by the output projection, and the gated MLP;
-    then the chunked cross-entropy projection (``chunked_ce_loss``), the
-    single-token decode attention (``make_decode_step``), and the
-    family extras (MoE routing, SSM state scan, cross-attention) when
-    the config enables them.  Every contraction is logged through
-    ``logger`` exactly as ``plan_contraction(..., logger=)`` would, so
-    the result replays through ``make_einsum_workload`` like a captured
-    production log.
+    traffic, this derives the einsum structures from one architecture's
+    widths (``TraceShape``): per layer the fused-attention core (Q/K/V
+    projections + QK^T + AV), the same chain extended by the output
+    projection, and the gated MLP; then the chunked cross-entropy
+    projection, the single-token decode attention, and the family
+    extras (MoE routing, SSM state scan, cross-attention) when the
+    shape enables them.  The result replays through
+    ``make_einsum_workload`` like a captured contraction log.
 
     The trace is deliberately *repetitive with shared structure* — every
     layer re-issues identical contractions, and the attention core is a
@@ -150,26 +176,17 @@ def model_planner_trace(cfg=None, batch: int = 4, seq: int = 64,
     for: repeats warm-start the C_max search, one-tensor extensions seed
     their solved sub-table.
     """
-    if cfg is None:
-        from repro.models.common import ModelConfig
-        cfg = ModelConfig(name="planner-small", family="dense",
-                          n_layers=3, d_model=256, n_heads=4,
-                          n_kv_heads=4, d_ff=512, vocab_size=4096)
     d = int(cfg.d_model)
     h = int(cfg.head_dim or (cfg.d_model // max(cfg.n_heads, 1)) or 64)
     ff = int(cfg.d_ff)
     out: list = []
 
     def emit(operands, output, sizes):
-        c = Contraction(tuple(operands), output, dict(sizes))
-        if logger is not None:
-            logger.log(c)
-        out.append(c)
+        out.append(Contraction(tuple(operands), output, dict(sizes)))
 
     attn_sizes = {"b": batch, "s": seq, "t": seq, "d": d, "e": d,
                   "f": d, "h": h, "v": h, "o": d}
-    n_layers = int(cfg.n_layers if layers is None else layers)
-    for i in range(n_layers):
+    for i in range(int(cfg.n_layers)):
         # hybrids interleave attention per layer_is_attn; every other
         # attention-bearing family applies it at each layer
         attn = bool(cfg.n_heads) and (
@@ -197,12 +214,12 @@ def model_planner_trace(cfg=None, batch: int = 4, seq: int = 64,
             emit(("bld", "dn", "nm", "blm", "md", "de"), "ble",
                  {"b": batch, "l": seq, "d": d, "n": cfg.ssm_state,
                   "m": cfg.ssm_state, "e": d})
-    # chunked cross-entropy (train/steps.chunked_ce_loss): the hidden
-    # chunk against the unembedding, with the z-loss reduction folded
+    # chunked cross-entropy: the hidden chunk against the unembedding,
+    # with the z-loss reduction folded
     emit(("cd", "dv", "vz"), "cz",
          {"c": 1024, "d": d, "v": int(cfg.vocab_size), "z": 1})
-    # decode-step attention (make_decode_step): one query token against
-    # a seq-long KV cache, through the output projection
+    # decode-step attention: one query token against a seq-long KV
+    # cache, through the output projection
     emit(("bd", "dh", "bte", "eh", "btf", "fv", "vo"), "bo",
          {"b": batch, "t": seq, "d": d, "e": d, "f": d, "h": h,
           "v": h, "o": d})
@@ -264,7 +281,7 @@ def plan_contraction(c: Contraction, cost: str = "max",
     then hit the cache, and ``method`` is chosen by the router.
 
     ``logger`` records the contraction into a ``ContractionLog`` for
-    later workload replay through the serving benchmark.
+    later workload replay through ``make_einsum_workload``.
     """
     if logger is not None:
         logger.log(c)

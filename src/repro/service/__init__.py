@@ -107,8 +107,8 @@ Above the single-process stack sits the distributed serving front end:
   admission (shed / downgrade / aging-promote) on the runtime side,
   deny-rate-fed ``AdmissionCeilings`` on the cluster-client side.
 
-Benchmark: ``benchmarks/serve_bench.py`` (``--quick`` for the CI gate in
-``scripts/smoke.sh``).  Demo: ``examples/planner_demo.py``.
+Benchmark: ``bench/run.py`` on the chip.  Demo:
+``examples/planner_demo.py``.
 """
 from repro.obs import (FlightRecorder, MetricsRegistry,  # noqa: F401
                        Tracer)

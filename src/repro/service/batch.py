@@ -34,8 +34,8 @@ serving-side concerns:
   gamma probing into the fused while-loop body — G gates on a leading
   axis, ~log_{G+1} instead of ~log_2 rounds per solve, still one
   dispatch.  Fewer sequential rounds buys latency on parallel-rich
-  hardware; the CPU container mostly shows it in the rounds-per-solve
-  counter (``benchmarks/serve_bench.py`` records both probe modes).
+  hardware; on the CPU it shows in the rounds-per-solve counter
+  (``tests/test_lattice_parity.py`` holds the reduction).
 
 Parity: whatever the tier, results are bit-identical in cost AND tree to
 single-query ``repro.core.dpconv.optimize`` — the candidate arrays and
@@ -73,7 +73,7 @@ class BatchPolicy:
     gamma_batch: int = 1        # fused probe width: 1 = binary search,
     # G > 1 = (G+1)-ary gamma probing inside the fused while loop
     solve_shards: int = 1       # solve-mesh width: D > 1 shard_maps each
-    # fused sweep over D devices (repro.launch.mesh.make_solve_mesh) —
+    # fused sweep over D devices (repro.core.mesh.make_solve_mesh) —
     # per-device layer memory drops 1/D, which is what lifts the fused
     # cap/out ceilings past n = 13 (engine.sharded_ceiling)
     shard_min_n: int = 14       # engage the mesh only at n >= this:

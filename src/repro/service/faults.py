@@ -162,7 +162,7 @@ class FaultPlan:
 
     @classmethod
     def chaos(cls, seed: int = 0, rate: float = 0.01) -> "FaultPlan":
-        """The fixed chaos mix serve_bench and the property test use:
+        """The fixed chaos mix the property tests use:
         every seam, ``rate`` total fault probability per dispatch spread
         evenly across the six fault sources."""
         r = rate / 6.0
@@ -384,7 +384,7 @@ class Quarantine:
 @dataclasses.dataclass
 class FaultStats:
     """Runtime-side resilience counters (the ``faults`` registry
-    provider; serve_bench's ``faults`` row reports them)."""
+    provider)."""
 
     retries: int = 0                 # retry dispatches scheduled
     retry_denied_headroom: int = 0   # backoff would blow the deadline

@@ -35,7 +35,8 @@ subset's canonical signature — fresh cardinalities, which can never hit,
 cost O(n) per subset instead of a ``2^r`` table permutation.  Seeds are always a pure performance hint:
 every consumer produces bit-identical tables, optima and trees with or
 without them (the seeded values equal what the lattice would compute —
-asserted by the parity property tests and the serve_bench reuse row).
+asserted by the parity property tests and by the model-trace replay
+test in ``tests/test_layercache.py``).
 
 Both stores are plain LRU ``OrderedDict``s like the plan cache; stats
 register on the server's ``MetricsRegistry`` as the ``layercache``

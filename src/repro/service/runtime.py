@@ -1,10 +1,9 @@
 """Async deadline-aware serving runtime — the scheduler over PlanServer.
 
 ``PlanServer._process`` answers "plan this micro-batch"; this module
-answers "keep answering under load".  The PR-1 serving loop was
-synchronous: a sub-millisecond cache hit queued behind a multi-second
-in-flight batched miss on the same lane (BENCH_serve.json: fused p50
-0.26 ms vs host p99 385 ms).  Mancini et al. (arXiv:2202.13511) make
+answers "keep answering under load".  A synchronous serving loop
+queues a sub-millisecond cache hit behind a multi-second in-flight
+batched miss on the same lane.  Mancini et al. (arXiv:2202.13511) make
 the case that optimizer throughput at scale is a *scheduling* problem
 as much as an algorithmic one; this runtime is that scheduling layer on
 top of the one-dispatch fused engines of PRs 2-4:

@@ -245,7 +245,7 @@ class PlanServer:
         """Compile the fused-engine executable buckets this server's
         policy can hit for relation counts ``ns``, before traffic
         arrives — kills the cold-bucket p99 spike of the first seconds
-        of serving (serve_bench's cold-latency row).  Respects the
+        of serving.  Respects the
         router's lane ceilings (tiny-``n`` and past-ceiling requests
         never reach the fused engine).  No-op for a host-engine server
         (but the manifest still records the requested buckets, so a

@@ -157,39 +157,33 @@ def _draw_slo(rng, slos, slo_p):
 
 
 # ------------------------------------------------------------ replay lane
-def einsum_replay_pool(include_model_traces: bool = True,
-                       logger=None) -> list:
+def einsum_replay_pool() -> list:
     """The replay lane's contraction pool.
 
     The canned model-stack trace (``einsum_path.builtin_trace``) plus
-    traces logged from the ``train/steps`` model planners for one config
-    per family (dense, MoE, SSM) — per-layer attention cores,
-    attention+projection chains, gated MLPs, chunked-CE, decode-step
-    attention, MoE routing, SSM scans (``model_planner_trace``).  The
-    model traces are deliberately repetitive with shared sub-structure
-    across templates, which is exactly the traffic the layer-fragment
-    cache exists for; the replay benchmark's ``reuse`` row is measured
-    on this pool.
+    the traces derived for one shape per family (dense, MoE, SSM):
+    per-layer attention cores, attention+projection chains, gated MLPs,
+    chunked-CE, decode-step attention, MoE routing, SSM scans
+    (``model_planner_trace``).  The model traces are deliberately
+    repetitive with shared sub-structure across templates, which is
+    exactly the traffic the layer-fragment cache exists for
+    (``tests/test_layercache.py`` serves this pool cold and seeded).
     """
-    from repro.models.common import ModelConfig
-    from repro.planner.einsum_path import builtin_trace, \
-        model_planner_trace
+    from repro.planner.einsum_path import (TraceShape, builtin_trace,
+                                           model_planner_trace)
 
     cs = list(builtin_trace())
-    if not include_model_traces:
-        return cs
-    for cfg in (
-        ModelConfig(name="replay-dense", family="dense", n_layers=2,
-                    d_model=256, n_heads=4, n_kv_heads=4, d_ff=512,
-                    vocab_size=4096),
-        ModelConfig(name="replay-moe", family="moe", n_layers=2,
-                    d_model=256, n_heads=4, n_kv_heads=4, d_ff=512,
-                    vocab_size=4096, n_experts=8, top_k=2),
-        ModelConfig(name="replay-ssm", family="ssm", n_layers=2,
-                    d_model=256, n_heads=0, n_kv_heads=0, d_ff=512,
-                    vocab_size=4096, ssm_state=16, head_dim=64),
+    for shape in (
+        TraceShape(name="replay-dense", family="dense", n_layers=2,
+                   d_model=256, n_heads=4, d_ff=512, vocab_size=4096),
+        TraceShape(name="replay-moe", family="moe", n_layers=2,
+                   d_model=256, n_heads=4, d_ff=512, vocab_size=4096,
+                   n_experts=8),
+        TraceShape(name="replay-ssm", family="ssm", n_layers=2,
+                   d_model=256, n_heads=0, d_ff=512, vocab_size=4096,
+                   ssm_state=16, head_dim=64),
     ):
-        cs.extend(model_planner_trace(cfg, logger=logger))
+        cs.extend(model_planner_trace(shape))
     return cs
 
 

@@ -68,6 +68,24 @@ def test_ring_deterministic_and_covering():
     assert set(a.owner(k) for k in keys) == set(ids)   # all get load
 
 
+def test_ring_partition_of_fresh_stream_spreads_over_four_replicas():
+    """A fresh out-cost stream keyed by canonical form: no ring owner
+    of a 4-replica ring holds so much of it that the modeled partition
+    makespan (one unit per request) is under 1.5x faster than one
+    replica serving everything."""
+    from repro.service import WorkloadSpec, make_workload
+    reqs = make_workload(WorkloadSpec(
+        n_requests=32, seed=0, n_range=(10, 11), fresh_frac=1.0,
+        cost_mix=(("out", 1.0),),
+        topologies=("chain", "star", "cycle", "sparse")))
+    ring = HashRing([f"r{i}" for i in range(4)])
+    load: dict = {}
+    for r in reqs:
+        owner = ring.owner(canonicalize(r.q, r.card).key)
+        load[owner] = load.get(owner, 0) + 1
+    assert len(reqs) >= 1.5 * max(load.values())
+
+
 def test_ring_successors_distinct_owner_first():
     ring = HashRing([f"r{i}" for i in range(5)], vnodes=32)
     for k in ("alpha", "beta", "gamma"):

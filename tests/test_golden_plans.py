@@ -3,8 +3,10 @@ host-reference plans in ``tests/fixtures/golden_plans.json``.
 
 The fixture (regenerated only deliberately, by
 ``scripts/regen_golden.py``) freezes bit-exact optima and serialized
-trees for the canned einsum replay trace and JOB-like chain/star
-workloads, computed on the host pipelines.  This test recomputes every
+trees for the canned einsum replay trace, JOB-like chain/star
+workloads, the chain/star/cycle/sparse/clique families at n = 10-13,
+two grids and the replay pool's model-trace contractions, computed on
+the host pipelines.  This test recomputes every
 entry with the **fused engines the serving tier defaults to** and diffs:
 a mismatch means either an unintended optimum/witness drift or a fused/
 host divergence — both must fail loudly, not skew silently.

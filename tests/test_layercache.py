@@ -316,6 +316,45 @@ def test_degraded_insert_never_clobbers_exact():
     assert r2.cache_hit and r2.status == "exact"
 
 
+def test_model_trace_replay_seeded_equals_cold():
+    """Reuse on model-trace traffic: the einsum replay pool's repeated
+    layers, served with the plan cache off so every request solves,
+    hit the fragment store and seed solves, and the seeded responses
+    are bitwise identical to cold ones.  A deadline-pressed replay with
+    the plan cache on never serves a degraded plan to a request that
+    carries no budget."""
+    from repro.service import make_einsum_workload
+    from repro.service.workload import einsum_replay_pool
+
+    spec = WorkloadSpec(n_requests=24, seed=5,
+                        cost_mix=(("max", 0.55), ("out", 0.30),
+                                  ("cap", 0.15)),
+                        relabel_frac=0.4)
+    reqs = make_einsum_workload(spec, contractions=einsum_replay_pool())
+    cold, _ = _server(enable_cache=False,
+                      enable_layer_cache=False).serve(list(reqs),
+                                                      closed_loop=True)
+    srv = _server(enable_cache=False)
+    srv.serve(list(reqs), closed_loop=True)   # fills the fragment store
+    srv.layers.stats = layercache_mod.LayerCacheStats()
+    seeded, _ = srv.serve(list(reqs), closed_loop=True)
+    st_ = srv.layers.stats
+    assert st_.search_hits + st_.value_hits > 0
+    assert st_.seeded_solves > 0
+    for c, s in zip(cold, seeded):
+        assert float(c.cost) == float(s.cost)
+        assert repr(c.tree) == repr(s.tree)
+
+    pressed = make_einsum_workload(
+        dataclasses.replace(spec, seed=6, budget_frac=0.3, budget_s=1e-6),
+        contractions=einsum_replay_pool())
+    resps, _ = _server().serve(list(pressed), closed_loop=True)
+    assert any(r.status == "degraded" for r in resps)
+    assert all(r.status != "degraded"
+               for req, r in zip(pressed, resps)
+               if req.latency_budget is None)
+
+
 # ------------------------------------------------ quarantine TTL bound
 def test_quarantine_ttl_boundary_half_open():
     """Refused on [t0, t0+ttl); admitted at exactly t0+ttl — 'refused

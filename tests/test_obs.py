@@ -309,6 +309,40 @@ def test_recorder_ring_bounded_incident_counts_exact():
     assert rec.counts["deadline_miss"] == 20  # ...exact counting
 
 
+def test_slo_stream_recorder_counts_equal_runtime_stats():
+    """A duplicate-heavy SLO-classed stream that sheds, downgrades and
+    misses deadlines: the flight recorder counts exactly the runtime's
+    incidents, every span closes on its lane's taxonomy, and each
+    batched solve is still one fused dispatch."""
+    classes = {"strict": SLOClass("strict", 1e-12, "refuse"),
+               "loose": SLOClass("loose", 1e-12, "downgrade"),
+               "tight": SLOClass("tight", 1.5),
+               "batch": SLOClass("batch", None)}
+    reqs = _reqs(n_requests=48, seed=3, n_range=(5, 8), fresh_frac=0.0,
+                 relabel_frac=0.8, zipf_a=2.0, rate=20.0,
+                 cost_mix=(("max", 0.7), ("cap", 0.2), ("out", 0.1)),
+                 slo_mix=(("strict", 0.2), ("loose", 0.2),
+                          ("tight", 0.3), ("batch", 0.3)))
+    srv, clk, rt = _mk(max_batch=4, slo_classes=classes)
+    engine_mod.reset_stats()
+    for r in sorted(reqs, key=lambda r: r.arrival):
+        rt.run_until(r.arrival)
+        rt.submit(r)
+    rt.drain()
+    st, counts = rt.stats, rt.recorder.counts
+    assert st.shed and st.downgraded and st.deadline_misses
+    assert counts["shed"] == st.shed + st.shed_backpressure
+    assert counts["downgraded"] == st.downgraded
+    assert counts["deadline_miss"] == st.deadline_misses
+    tr = rt.tracer.stats()
+    assert tr["requests"] == len(reqs)
+    assert tr["open_spans"] == tr["unclosed_spans"] == 0
+    assert tr["lane_shape_mismatches"] == 0
+    est = engine_mod.stats()
+    assert est.solves > 0 and est.dispatches == est.solves
+    assert est.host_extractions == 0
+
+
 # --------------------------------------------------- runtime stats schema
 def test_runtime_stats_as_dict_schema_snapshot():
     srv, clk, rt = _mk()

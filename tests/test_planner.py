@@ -123,3 +123,42 @@ def test_datajoin_execute_matches_plan_order_invariance():
     assert outs[0] == outs[1]
     # expected row count: examples with doc < 28 (those have quality rows)
     assert len(outs[0]) == int((ex["doc"] < 28).sum())
+
+
+def test_einsum_replay_pool_matches_pinned_fixture():
+    """The replay pool (canned trace + the model traces derived from
+    each replay shape) is pinned contraction by contraction, in order:
+    a change to how traces are derived shows up here as a diff."""
+    import json
+    import os
+
+    from repro.service.workload import einsum_replay_pool
+
+    path = os.path.join(os.path.dirname(__file__), "fixtures",
+                        "einsum_replay_pool.json")
+    with open(path) as f:
+        pinned = json.load(f)
+    got = [{"operands": list(c.operands), "output": c.output,
+            "sizes": c.sizes} for c in einsum_replay_pool()]
+    assert got == pinned
+
+
+def test_hypergraph_plan_respects_connectivity():
+    """Non-inner-join hyperedge: plans must not join either side of the
+    hyperedge before the side is complete."""
+    from repro.core.querygraph import QueryGraph, make_cardinalities
+    from repro.core.jointree import extract_tree_out
+    # relations 0-1 joined; 2-3 joined; a hyperedge ({0,1},{2,3})
+    q = QueryGraph(4, ((0, 1), (2, 3)), hyperedges=((0b0011, 0b1100),))
+    card = make_cardinalities(q, seed=0)
+    conn = q.connected_mask()
+    dp = dpsub_out(card, 4, connected=conn)
+    assert np.isfinite(dp[-1])
+    tree = extract_tree_out(dp, card, 4)
+    # every internal node must be a connected set under hypergraph rules
+    for m in tree.internal_masks():
+        assert q.is_connected(m), bin(m)
+    # sets mixing one side of the hyperedge with part of the other are
+    # not connected and must be absent
+    assert not q.is_connected(0b0101)
+    assert np.isinf(dp[0b0101])
