@@ -204,15 +204,28 @@ def permute_card(card: np.ndarray, n: int, perm: Sequence[int]) -> np.ndarray:
     Pure gather — values are moved, never recomputed, so two tables that
     differ only by a relabeling stay byte-identical after canonicalization
     (this is what makes the plan-cache key exact).
+
+    The index ``perm(S)`` is the OR of two small lookup tables, one over
+    the low ``min(n, 8)`` bits of ``S`` and one over the rest, joined by
+    one broadcast: O(2^8 + 2^(n-8)) shift-or work, not n passes over 2^n.
     """
-    size = 1 << n
-    S = np.arange(size, dtype=np.int64)
-    Sp = np.zeros(size, dtype=np.int64)
-    for i in range(n):
-        Sp |= ((S >> i) & 1) << int(perm[i])
+    low = min(n, 8)
+    lo = _permuted_bits(range(low), perm)
+    hi = _permuted_bits(range(low, n), perm)
+    Sp = (hi[:, None] | lo[None, :]).ravel()
     out = np.empty_like(np.asarray(card))
     out[Sp] = np.asarray(card)
     return out
+
+
+def _permuted_bits(bits: range, perm: Sequence[int]) -> np.ndarray:
+    """``(2^len(bits),)`` int64: ``t[k]`` places bit j of ``k`` at
+    ``perm[bits[j]]``, i.e. relabels the part of a mask over ``bits``."""
+    k = np.arange(1 << len(bits), dtype=np.int64)
+    t = np.zeros_like(k)
+    for j, i in enumerate(bits):
+        t |= ((k >> j) & 1) << int(perm[i])
+    return t
 
 
 # ------------------------------------------------------------ cardinalities

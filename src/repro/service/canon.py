@@ -38,6 +38,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import math
+import typing
 
 import numpy as np
 
@@ -57,6 +58,7 @@ class CanonicalForm:
     signature: str          # coarse topology-class signature
     q: QueryGraph           # canonical-label query graph
     card: np.ndarray        # canonical-label cardinality table
+    leaves: int             # refinement leaves whose tables were built
 
     @property
     def inverse_perm(self) -> tuple:
@@ -108,23 +110,33 @@ def _refine(q: QueryGraph, card: np.ndarray, colors: list) -> list:
     return colors
 
 
-def _canonical_bytes(q: QueryGraph, card: np.ndarray, perm) -> bytes:
+class _Leaf(typing.NamedTuple):
+    """One refinement leaf's canonical form, each part built once."""
+    byt: bytes              # canonical bytes: graph header + float64 table
+    perm: tuple             # perm[i] = canonical label of relation i
+    q: QueryGraph           # the graph relabelled by perm
+    card: np.ndarray        # the table permuted by perm, card's dtype
+
+
+def _leaf(q: QueryGraph, card: np.ndarray, perm: tuple) -> _Leaf:
     qc = relabel(q, perm)
     cc = permute_card(card, q.n, perm)
     head = (f"n={q.n};e={qc.edges};h={qc.hyperedges};"
             .encode())
-    return head + np.ascontiguousarray(cc, np.float64).tobytes()
+    return _Leaf(head + np.ascontiguousarray(cc, np.float64).tobytes(),
+                 perm, qc, cc)
 
 
-def canonical_perm(q: QueryGraph, card: np.ndarray,
-                   branch_cap: int = 64) -> tuple:
-    """Canonical relabeling via refinement + capped individualization."""
+def _canonical_leaf(q: QueryGraph, card: np.ndarray,
+                    branch_cap: int) -> "tuple[_Leaf, int]":
+    """Refinement + capped individualization: the leaf with the smallest
+    canonical bytes, and the number of leaves whose tables were built."""
     n = q.n
     deg = [bin(int(a)).count("1") for a in q.adjacency()]
     init = [(deg[i], _qlog(card[1 << i])) for i in range(n)]
     colors = _refine(q, card, _compress(init))
 
-    best: list = [None, None]          # [bytes, perm]
+    best: list = [None]                # [_Leaf]
     leaves = [0]
 
     def finish(colors: list):
@@ -132,9 +144,9 @@ def canonical_perm(q: QueryGraph, card: np.ndarray,
         perm = [0] * n
         for rank, i in enumerate(order):
             perm[i] = rank
-        byt = _canonical_bytes(q, card, perm)
-        if best[0] is None or byt < best[0]:
-            best[0], best[1] = byt, tuple(perm)
+        leaf = _leaf(q, card, tuple(perm))
+        if best[0] is None or leaf.byt < best[0].byt:
+            best[0] = leaf
 
     def rec(colors: list):
         if leaves[0] >= branch_cap and best[0] is not None:
@@ -157,7 +169,13 @@ def canonical_perm(q: QueryGraph, card: np.ndarray,
             rec(_refine(q, card, _compress(forked)))
 
     rec(colors)
-    return best[1]
+    return best[0], leaves[0]
+
+
+def canonical_perm(q: QueryGraph, card: np.ndarray,
+                   branch_cap: int = 64) -> tuple:
+    """Canonical relabeling via refinement + capped individualization."""
+    return _canonical_leaf(q, card, branch_cap)[0].perm
 
 
 def topology_signature(q: QueryGraph) -> str:
@@ -185,16 +203,14 @@ def topology_signature(q: QueryGraph) -> str:
 
 def canonicalize(q: QueryGraph, card: np.ndarray,
                  branch_cap: int = 64) -> CanonicalForm:
-    perm = canonical_perm(q, card, branch_cap=branch_cap)
-    qc = relabel(q, perm)
-    cc = permute_card(card, q.n, perm)
-    byt = _canonical_bytes(q, card, perm)
+    leaf, leaves = _canonical_leaf(q, card, branch_cap)
     return CanonicalForm(
-        key=hashlib.sha256(byt).hexdigest(),
-        perm=perm,
+        key=hashlib.sha256(leaf.byt).hexdigest(),
+        perm=leaf.perm,
         signature=topology_signature(q),
-        q=qc,
-        card=cc,
+        q=leaf.q,
+        card=leaf.card,
+        leaves=leaves,
     )
 
 
@@ -286,10 +302,10 @@ def subset_signature(q: QueryGraph, card: np.ndarray, mask: int,
     across queries exactly on relabeled-identical induced sub-problems.
     """
     q_sub, card_sub, rels = induced_subproblem(q, card, mask)
-    perm = canonical_perm(q_sub, card_sub, branch_cap=branch_cap)
-    byt = b"frag;" + _canonical_bytes(q_sub, card_sub, perm)
+    leaf, _ = _canonical_leaf(q_sub, card_sub, branch_cap)
+    byt = b"frag;" + leaf.byt
     return SubsetForm(key=hashlib.sha256(byt).hexdigest(),
-                      rels=rels, perm=perm)
+                      rels=rels, perm=leaf.perm)
 
 
 def subset_fingerprint(q: QueryGraph, card: np.ndarray, mask: int) -> str:

@@ -213,6 +213,7 @@ class ClassStats:
 @dataclasses.dataclass
 class RuntimeStats:
     submitted: int = 0
+    canon_leaves: int = 0       # refinement leaves canonicalisation built
     served: int = 0
     fast_path_hits: int = 0
     overtakes: int = 0          # fast-path answers with a solve in flight
@@ -269,7 +270,8 @@ class RuntimeStats:
 
     def as_dict(self) -> dict:
         return {
-            "submitted": self.submitted, "served": self.served,
+            "submitted": self.submitted,
+            "canon_leaves": self.canon_leaves, "served": self.served,
             "fast_path_hits": self.fast_path_hits,
             "overtakes": self.overtakes, "coalesced": self.coalesced,
             "coalesce_rate": round(self.coalesce_rate, 4),
@@ -673,6 +675,7 @@ class ServingRuntime:
         admit = ticket.spans["admit"] = ticket.span.child("admit", at=now)
         with phase("canonicalize", admit, req_id=req.req_id):
             form = ticket.form = canonicalize(req.q, card)
+        self.stats.canon_leaves += form.leaves
         ticket.admit_phases = 1
         budget = req.latency_budget
         if budget is None and slo is not None:

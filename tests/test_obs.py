@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import engine as engine_mod
-from repro.core.querygraph import chain, make_cardinalities
+from repro.core.querygraph import chain, clique, make_cardinalities
 from repro.obs.export import prometheus, span_phase_summary
 from repro.obs.metrics import BOUNDS, Histogram, MetricsRegistry
 from repro.obs.recorder import FlightRecorder
@@ -351,7 +351,8 @@ def test_runtime_stats_as_dict_schema_snapshot():
     rt.drain()
     d = rt.stats.as_dict()
     assert set(d) == {
-        "submitted", "served", "fast_path_hits", "overtakes",
+        "submitted", "canon_leaves", "served", "fast_path_hits",
+        "overtakes",
         "coalesced", "coalesce_rate", "downgraded", "shed",
         "shed_backpressure", "shed_rate", "batches",
         "mean_batch_occupancy", "steals", "hedges", "lanes",
@@ -375,6 +376,36 @@ def test_server_registry_snapshot_has_all_providers():
     assert snap["providers"]["tracer"]["open_spans"] == 0
     # span-duration histograms landed in the metric section
     assert any(k.startswith("trace.") for k in snap["metrics"])
+
+
+def _uniform_clique(n):
+    """A clique whose cardinality depends on |S| alone: every relation
+    ties, so canonicalisation's individualisation search branches."""
+    sizes = np.array([bin(s).count("1") for s in range(1 << n)])
+    return clique(n), np.power(10.0, sizes)
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_canon_leaves_counts_tables_per_request(tied):
+    """``canon_leaves`` under the ``runtime`` provider: one leaf per
+    request whose refinement is discrete, more for a tied clique."""
+    srv, clk, rt = _mk(max_batch=2)
+    if tied:
+        q, card = _uniform_clique(6)
+        reqs = [PlanRequest(q=q, card=card * (1 + k), cost="max", req_id=k)
+                for k in range(3)]
+    else:
+        reqs = [PlanRequest(q=chain(6), card=make_cardinalities(
+            chain(6), seed=k), cost="max", req_id=k) for k in range(3)]
+    for r in reqs:
+        rt.submit(r)
+    rt.drain()
+    runtime = srv.registry.snapshot()["providers"]["runtime"]
+    assert runtime["submitted"] == 3
+    if tied:
+        assert runtime["canon_leaves"] > runtime["submitted"]
+    else:
+        assert runtime["canon_leaves"] == runtime["submitted"]
 
 
 # ------------------------------------------------ explain + connected cap

@@ -5,7 +5,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.querygraph import (QueryGraph, clique, chain, star, cycle,
                                    random_sparse, make_cardinalities,
-                                   paper_clique_instance)
+                                   paper_clique_instance, permute_card)
 from repro.core.bitset import popcounts, bits_of, popcount_int
 
 
@@ -66,3 +66,32 @@ def test_bitset_utils():
     pc = popcounts(5)
     for s in range(32):
         assert pc[s] == bin(s).count("1")
+
+
+def _shift_or_permute_card(card, n, perm):
+    """permute_card as n full-width shift-or passes over the lattice."""
+    S = np.arange(1 << n, dtype=np.int64)
+    Sp = np.zeros(1 << n, dtype=np.int64)
+    for i in range(n):
+        Sp |= ((S >> i) & 1) << int(perm[i])
+    out = np.empty_like(np.asarray(card))
+    out[Sp] = np.asarray(card)
+    return out
+
+
+@pytest.mark.parametrize("n", [0, 1, 7, 8, 9, 15, 16])
+def test_permute_card_bytewise_index_matches_shift_or(n):
+    rng = np.random.default_rng(1000 + n)
+    card = rng.uniform(1.0, 1e8, 1 << n)
+    for _ in range(3):
+        perm = [int(p) for p in rng.permutation(n)]
+        got = permute_card(card, n, perm)
+        assert got.dtype == card.dtype
+        assert got.tobytes() == _shift_or_permute_card(card, n, perm).tobytes()
+        inv = [0] * n
+        for i, p in enumerate(perm):
+            inv[p] = i
+        assert permute_card(got, n, inv).tobytes() == card.tobytes()
+    # dtype follows the input
+    ints = np.arange(1 << n, dtype=np.int32)
+    assert permute_card(ints, n, list(range(n))).dtype == np.int32

@@ -1,4 +1,9 @@
 """Canonicalization + plan cache: key invariance and relabeled reuse."""
+import functools
+import importlib.util
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -7,8 +12,8 @@ from repro.core.querygraph import (QueryGraph, chain, clique, cycle, grid,
                                    random_sparse, relabel, star)
 from repro.core.dpconv import optimize
 from repro.service.cache import CachedPlan, PlanCache
-from repro.service.canon import (canonicalize, relabel_tree,
-                                 topology_signature)
+from repro.service.canon import (canonical_perm, canonicalize, relabel_tree,
+                                 subset_signature, topology_signature)
 from repro.service.server import PlanServer
 
 
@@ -123,3 +128,66 @@ def test_cache_disabled_never_hits():
     resp = srv.plan_one(q, card, cost="max")
     assert not resp.cache_hit
     assert srv.cache.stats.lookups == 0
+
+
+# ------------------------------------------------- pinned canonical keys
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "tests", "fixtures", "canon_keys.json")) as fh:
+    PINNED = json.load(fh)
+
+
+@functools.lru_cache(maxsize=None)
+def _pin_script():
+    """``scripts/pin_canon_keys.py``, which builds the fixture's queries."""
+    spec = importlib.util.spec_from_file_location(
+        "pin_canon_keys", os.path.join(ROOT, "scripts", "pin_canon_keys.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _pinned_cases() -> dict:
+    return {name: (q, card) for name, q, card in _pin_script().cases()}
+
+
+@pytest.mark.parametrize("entry", PINNED["queries"],
+                         ids=[e["name"] for e in PINNED["queries"]])
+def test_canonical_form_matches_pinned_fixture(entry):
+    """Key, perm and canonical table are bit-identical to the fixture
+    written before each leaf's table was built once."""
+    q, card = _pinned_cases()[entry["name"]]
+    f = canonicalize(q, card)
+    assert f.key == entry["key"]
+    assert list(f.perm) == entry["perm"]
+    assert canonical_perm(q, card) == f.perm
+    assert _pin_script().card_sha256(f.card) == entry["card_sha256"]
+    assert f.card.dtype == np.float64
+    # the form's own graph and table are the request relabelled by perm
+    assert f.q == relabel(q, f.perm)
+    assert f.card.tobytes() == permute_card(card, q.n, f.perm).tobytes()
+
+
+@pytest.mark.parametrize("entry", PINNED["fragments"],
+                         ids=[e["name"] for e in PINNED["fragments"]])
+def test_subset_signature_matches_pinned_fixture(entry):
+    q, card = _pinned_cases()[entry["case"]]
+    s = subset_signature(q, card, entry["mask"])
+    assert s.key == entry["key"]
+    assert list(s.rels) == entry["rels"]
+    assert list(s.perm) == entry["perm"]
+
+
+def test_canonical_leaves_count_tables_built():
+    cases = _pinned_cases()
+    # random cardinalities refine to discrete colours: one leaf
+    for name in ("paper_clique15_s0", "paper_star15_s0", "sparse13",
+                 "hyper8"):
+        assert canonicalize(*cases[name]).leaves == 1, name
+    # every relation of the uniform clique ties: the search branches to
+    # its cap, and a lower cap builds fewer tables for the same key
+    q, card = cases["uniform_clique8"]
+    assert canonicalize(q, card).leaves == 64
+    capped = canonicalize(q, card, branch_cap=4)
+    assert capped.leaves == 4
+    assert capped.key == canonicalize(q, card).key
